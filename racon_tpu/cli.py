@@ -153,17 +153,18 @@ usage: racon_tpu [options ...] <sequences> <overlaps> <target sequences>
             ladders; output is byte-identical either way (mirrors
             RACON_TPU_ADAPTIVE_BUCKETS)
         --tpu-compile-cache <dir>
-            default: none
+            default: <checkout>/.jax_cache
             persistent XLA compilation cache directory: repeated runs
             skip recompiles, including adaptive-bucket runs whose shapes
-            are data-derived (mirrors RACON_TPU_COMPILE_CACHE /
-            JAX_COMPILATION_CACHE_DIR)
+            are data-derived (mirrors RACON_TPU_COMPILE_CACHE); yields
+            to JAX_COMPILATION_CACHE_DIR when that is set
         --tpu-pallas <0|1|auto>
             default: 0
             hand-tiled Pallas device kernels for the banded aligner and
             the session POA sweep: 1 = whenever the VMEM envelope fits,
             auto = per-bucket from the persisted autotuner winner table
-            (profile with tools/tpu_smoke.py; buckets without an entry
+            (profile with sched.autotune.profile_production; buckets
+            without an entry
             dispatch XLA), 0 = XLA programs only. Output is
             byte-identical in every mode (mirrors RACON_TPU_PALLAS)
         --tpu-dtype <auto|int32|int16>
@@ -510,6 +511,15 @@ def main(argv: list[str] | None = None) -> int:
         # records into its own recorder
         set_log_level(opts["tpu_log_level"] or None)
         trace.reset()
+        if opts["tpu_poa_batches"] > 0 or opts["tpu_aligner_batches"] > 0:
+            from .sched import enable_compile_cache
+
+            # device runs always keep their compiles (the placement
+            # rule: JAX_COMPILATION_CACHE_DIR, else the option, else
+            # the checkout's .jax_cache)
+            opts["tpu_compile_cache"] = enable_compile_cache(
+                opts["tpu_compile_cache"]
+                or os.environ.get("RACON_TPU_COMPILE_CACHE"))
         polisher = create_polisher(
             opts["paths"][0], opts["paths"][1], opts["paths"][2],
             PolisherType.kF if opts["fragment_correction"]

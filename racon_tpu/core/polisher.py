@@ -222,6 +222,8 @@ class Polisher:
         self.n_aligner_pairs = 0
         self.n_aligner_device = 0
         self.n_aligner_host_fallback = 0
+        #: consensus-phase window placement (ops/poa.BatchPOA)
+        self.window_counts: dict = {}
         # the unified metrics registry (obs/metrics.py): the pipeline
         # stage counters, the resilience degradation counters, the
         # scheduler's occupancy telemetry and the aligner accounting, one
@@ -946,6 +948,7 @@ class Polisher:
         with profile_ctx, pipeline:
             engine.generate_consensus(self.windows, self.trim)
         dt = _time.perf_counter() - t_consensus
+        self.window_counts = dict(engine.window_counts)
         snap_occ = self.scheduler.stats.snapshot()
         self.emit_progress(
             len(self.windows), len(self.windows),

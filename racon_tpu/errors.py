@@ -1,4 +1,4 @@
-"""Error types: user-facing failures and the device-failure taxonomy.
+"""Error types: user-facing failures and the device-failure hierarchy.
 
 The reference hard-exits with a diagnostic prefix `[racon::Class::method] error: ...`
 (e.g. src/polisher.cpp:206-209, src/overlap.cpp:148-153, src/window.cpp:19-23).
@@ -7,7 +7,7 @@ stderr + exit(1) so the observable behavior matches.
 
 The reference's only *device* failure posture is a hard exit via
 `CU_CHECK_ERR` (cudautils.hpp:10-18). Here device-side failures get their
-own taxonomy under `DeviceError` so degradation decisions (retry, host
+own hierarchy under `DeviceError` so degradation decisions (retry, host
 fallback, per-window quarantine — racon_tpu/resilience/) and the strict
 mode key on error CLASS, not string matching:
 

@@ -5,24 +5,22 @@ ops/poa_pallas.window_sweep): the anti-diagonal wavefront of
 ops/align._banded_nw_kernel as a hand-tiled kernel, one pair per
 sequential grid step with the WHOLE job resident in VMEM:
 
-  - the two rolling wavefronts live in VMEM scratch as `score_dtype`
-    rows ([1, band]); the XLA program instead carries them through a
-    `lax.scan` whose state round-trips HBM every anti-diagonal;
-  - the backpointer plane ([n_waves, band] int8 — codes are 2-bit
-    values, stored one per byte because byte rows keep every store a
-    plain vector op; the XLA program's packed uint8 plane must leave
-    the chip, ~n_waves*band/4 bytes per lane, while this one never
-    does) lives in VMEM scratch;
+  - the two rolling wavefronts live in VMEM scratch as int32 rows; the
+    XLA program instead carries them through a `lax.scan` whose state
+    round-trips HBM every anti-diagonal;
+  - the backpointer plane (2-bit codes, 16 per int32 word, so every
+    store is a whole 32-bit row) lives in VMEM scratch, where the XLA
+    program's plane is an HBM buffer;
   - the traceback runs in-kernel (scalar pointer chase over the VMEM
     backpointers, mirroring window_sweep), so the kernel's outputs are
     only the op-code path (<= m+n entries), its length, the final
-    distance and the band-edge flag — a ~band/4-fold cut in
-    device->host traffic.
+    distance and the band-edge flag — what the XLA program's device
+    traceback returns too.
 
 DP values, band tracking and tie order replicate _banded_nw_kernel
 EXACTLY (same formulas, same INF clamp, same diag < up < left order),
-and the band-shifted neighbour reads are plain dynamic slices because
-the host pre-extends the operands (`build_ext`): q_ext[p] =
+and the band-shifted neighbour reads are lane rolls because the host
+pre-extends the operands (`build_ext`): q_ext[p] =
 q[clip(p-1, 0, edge-1)] and t_ext[p] = t[clip(2*edge-1-p, 0, edge-1)],
 so wavefront d of lane offset a0 reads q at slice start a0 and t at
 slice start 2*edge + a0 - d — including the exact clip values the XLA
@@ -41,13 +39,17 @@ import functools
 import numpy as np
 
 #: VMEM the resident job may use — shared budget with the POA kernel
-from .poa_pallas import VMEM_BUDGET
+from .poa_pallas import SMEM_BUDGET, VMEM_BUDGET, _round128
 
 BP_DIAG, BP_UP, BP_LEFT = 0, 1, 2  # ops/align.py's codes
 
 
-def _round128(n: int) -> int:
-    return (n + 127) // 128 * 128
+#: 2-bit backpointer codes packed per int32 word of the plane
+_BP_PER_WORD = 16
+
+
+def _bp_rows(n_waves: int) -> int:
+    return ((n_waves + _BP_PER_WORD - 1) // _BP_PER_WORD + 7) // 8 * 8
 
 
 def ext_widths(edge: int, band: int) -> tuple[int, int]:
@@ -56,20 +58,22 @@ def ext_widths(edge: int, band: int) -> tuple[int, int]:
 
 
 def fits_vmem(edge: int, band: int, dtype: str = "int32") -> bool:
-    """True when one lane of bucket (edge, band) is resident-VMEM
-    feasible: the backpointer plane, the rolling wavefronts, AND the
-    per-grid-step operand blocks (offsets, extended q/t, outputs — all
-    int32 in VMEM; the original fits_vmem bug of budgeting only the
-    scratch is not repeated here) fit the shared budget with slack."""
+    """True when one lane of bucket (edge, band) is resident-on-chip
+    feasible, counted at the chip's tiling: the packed backpointer plane,
+    the two wavefront rows, and the double-buffered operand and output
+    blocks (each [1, X] block a full 8-sublane int32 tile) fit the
+    shared VMEM budget with slack, and the double-buffered band offsets
+    fit the SMEM budget. The kernel computes in int32 whatever `dtype`
+    says, so the footprint does not depend on it."""
+    del dtype
     n_waves = 2 * edge + 1
-    lq, lt = ext_widths(edge, band)
     nw_pad = _round128(n_waves)
-    # int8 bp rows are tiled to >= 128 lanes on chip
-    bp = _round128(n_waves + 32) * max(_round128(band), 128)
-    dbytes = 2 if dtype == "int16" else 4
-    waves = 2 * max(_round128(band), 128) * dbytes * 8  # 8-sublane tiles
-    operands = (nw_pad + lq + lt + nw_pad + 128) * 4
-    return bp + waves + operands + (1 << 20) <= VMEM_BUDGET
+    lq, lt = ext_widths(edge, band)
+    bw = _round128(band) + 128
+    bp = _bp_rows(n_waves) * bw * 4
+    tiles = 8 * 4 * (2 * bw + 2 * (lq + bw + lt + bw + nw_pad + 128))
+    return (bp + tiles + (1 << 20) <= VMEM_BUDGET
+            and 2 * nw_pad * 4 <= SMEM_BUDGET)
 
 
 def build_ext(q_arr: np.ndarray, t_arr: np.ndarray,
@@ -99,8 +103,19 @@ def wavefront_align(edge: int, band: int, score_dtype: str = "int32",
     ([B, Lx//4] uint8, from encode.pack_2bit over build_ext's output)
     and unpacks + PAD-restores them with XLA ops before the kernel —
     a 4x cut in host->device sequence traffic, byte-identical by
-    construction. `score_dtype` picks the wavefront dtype; int16 is
-    only legal under ops/dtypes.aligner_int16_ok's envelope proof.
+    construction. `score_dtype` picks the wavefront value range; int16
+    is only legal under ops/dtypes.aligner_int16_ok's envelope proof
+    (the kernel computes in int32 either way: the chip has no 16-bit
+    vector ALU, and inside the proof the two agree bit for bit).
+
+    Layout on chip: the pair lengths arrive by scalar prefetch and the
+    band offsets as an SMEM block (read one per wavefront); wavefronts
+    are [1, BW] int32 VMEM rows (BW = band rounded up to 128 lanes, plus
+    128 lanes of INF). The band-shifted
+    neighbour reads and the sequence windows are lane rolls: a
+    128-aligned window of q_ext/t_ext is loaded at a dynamic offset and
+    rolled by the remainder. Backpointers pack 16 2-bit codes per int32
+    word, so every store is a whole int32 row.
     """
     import jax
     import jax.numpy as jnp
@@ -110,89 +125,100 @@ def wavefront_align(edge: int, band: int, score_dtype: str = "int32",
     n_waves = 2 * edge + 1
     nw_pad = _round128(n_waves)
     lq, lt = ext_widths(edge, band)
-    DT = jnp.int16 if score_dtype == "int16" else jnp.int32
+    BW = _round128(band) + 128
+    LQ, LT = lq + BW, lt + BW
     INF = (1 << 14) if score_dtype == "int16" else (1 << 28)
+    i32 = jnp.int32
 
-    def kernel(scal_ref, offs_ref, qx_ref, tx_ref, ops_ref, meta_ref,
-               s1, s2, bps):
-        m = scal_ref[0, 0]
-        n = scal_ref[0, 1]
-        INFD = jnp.asarray(INF, DT)
-        ks = jax.lax.broadcasted_iota(jnp.int32, (1, band), 1)
-        s1[0:1, :] = jnp.full((1, band), INF, DT)
-        s2[0:1, :] = jnp.full((1, band), INF, DT)
-        ops_ref[0:1, :] = jnp.zeros((1, nw_pad), jnp.int32)
-        pad = jnp.full((1, 1), INF, DT)
+    def kernel(scal_ref, offs_ref, qx_ref, tx_ref, ops_ref, meta_ref, bps,
+               s1_ref, s2_ref):
+        b = pl.program_id(0)
+        m = scal_ref[2 * b]
+        n = scal_ref[2 * b + 1]
+        ks = jax.lax.broadcasted_iota(i32, (1, BW), 1)
+        infv = jnp.full((1, BW), INF, i32)
+
+        def vec(s):
+            return jnp.full((1, BW), s, i32)
+
+        def shifted(s, by):
+            """s[k + by] for 0 <= k + by < band, INF elsewhere."""
+            src = ks + by
+            return jnp.where((src >= 0) & (src < band),
+                             pltpu.roll(s, (BW - by) % BW, 1), infv)
+
+        def window(ref, at):
+            """ref[at + k] for k < BW - 127: an aligned load rolled by
+            the remainder."""
+            st = pl.multiple_of(at // 128 * 128, 128)
+            w = ref[:, pl.ds(st, BW)]
+            return pltpu.roll(w, (BW - (at - st)) % BW, 1)
 
         def wave(d, carry):
             # the loop index arrives as int64 when another kernel build
             # (poa_fused) has flipped jax_enable_x64 for the process;
             # every index expression below must stay int32
-            d = jnp.asarray(d, jnp.int32)
-            z = jnp.int32(0)
+            d = jnp.asarray(d, i32)
             a1, a2, dist = carry
+            s1 = s1_ref[...]
+            s2 = s2_ref[...]
             a0 = offs_ref[0, d]
-            ext1 = jnp.concatenate([pad, s1[0:1, :], pad], axis=1)
-            ext2 = jnp.concatenate([pad, s2[0:1, :], pad], axis=1)
-            da = a0 - a1
-            db = a0 - a2
-            i = a0 + ks
-            j = d - i
-            # neighbour reads as shifted slices of the rolling rows:
-            # up (d-1, i-1) = s1[k + da - 1], left (d-1, i) = s1[k + da],
-            # diag (d-2, i-1) = s2[k + db - 1]; the INF border of ext*
-            # reproduces the XLA gather's out-of-band INF exactly
-            up = jnp.where(i >= 1,
-                           jax.lax.dynamic_slice(ext1, (z, da), (1, band)),
-                           INFD)
-            left = jnp.where(j >= 1,
-                             jax.lax.dynamic_slice(ext1, (z, da + 1),
-                                                   (1, band)), INFD)
+            i = vec(a0) + ks
+            j = vec(d) - i
+            # neighbour reads from the rolling wavefronts: up (d-1, i-1)
+            # = s1[k + da - 1], left (d-1, i) = s1[k + da], diag
+            # (d-2, i-1) = s2[k + db - 1] — INF outside the band, as the
+            # XLA program's clipped gather gives
+            up = jnp.where(i >= 1, shifted(s1, a0 - a1 - 1), infv)
+            left = jnp.where(j >= 1, shifted(s1, a0 - a1), infv)
             diag = jnp.where((i >= 1) & (j >= 1),
-                             jax.lax.dynamic_slice(ext2, (z, db),
-                                                   (1, band)), INFD)
-            qi = jax.lax.dynamic_slice(qx_ref[0:1, :], (z, a0), (1, band))
-            tj = jax.lax.dynamic_slice(tx_ref[0:1, :],
-                                       (z, 2 * edge + a0 - d), (1, band))
-            sub = jnp.where(qi == tj, 0, 1).astype(DT)
+                             shifted(s2, a0 - a2 - 1), infv)
+            qi = window(qx_ref, a0)
+            tj = window(tx_ref, 2 * edge + a0 - d)
+            sub = jnp.where(qi == tj, 0, 1)
 
             cd = diag + sub
-            cu = up + jnp.asarray(1, DT)
-            cl = left + jnp.asarray(1, DT)
+            cu = up + 1
+            cl = left + 1
             # fixed tie order: diag, up, left (ops/align.py)
             score = cd
-            bp = jnp.zeros((1, band), jnp.int32) + BP_DIAG
-            bp = jnp.where(cu < score, BP_UP, bp)
+            bp = jnp.where(cu < score, BP_UP, BP_DIAG)
             score = jnp.minimum(score, cu)
             bp = jnp.where(cl < score, BP_LEFT, bp)
             score = jnp.minimum(score, cl)
-            origin = (i == 0) & (j == 0)
-            score = jnp.where(origin, jnp.asarray(0, DT), score)
-            valid = (i >= 0) & (i <= m) & (j >= 0) & (j <= n)
-            score = jnp.where(valid, jnp.minimum(score, INFD), INFD)
+            score = jnp.where((i == 0) & (j == 0), 0, score)
+            valid = ((i >= 0) & (i <= vec(m)) & (j >= 0) & (j <= vec(n))
+                     & (ks < band))
+            score = jnp.where(valid, jnp.minimum(score, INF), INF)
 
-            at_end = (i == m) & (j == n)
-            dist = jnp.where(
-                jnp.any(at_end),
-                jnp.min(jnp.where(at_end, score, INFD)).astype(jnp.int32),
-                dist)
+            at_end = (i == vec(m)) & (j == vec(n)) & (ks < band)
+            end = jnp.min(jnp.where(at_end, score, infv))
+            dist = jnp.where(jnp.max(at_end.astype(i32)) > 0, end, dist)
 
-            bps[pl.ds(d, 1), :] = bp.astype(jnp.int8)
-            s2[0:1, :] = s1[0:1, :]
-            s1[0:1, :] = score
+            w = d // _BP_PER_WORD
+            sh = 2 * (d % _BP_PER_WORD)
+            old = jnp.where(vec(sh) == 0, 0, bps[pl.ds(w, 1), :])
+            bps[pl.ds(w, 1), :] = old | (bp << sh)
+            s2_ref[...] = s1
+            s1_ref[...] = score
             return a0, a1, dist
 
+        s1_ref[...] = infv
+        s2_ref[...] = infv
         _, _, dist = jax.lax.fori_loop(
-            0, n_waves, wave,
-            (jnp.int32(0), jnp.int32(0), jnp.int32(INF)))
+            0, n_waves, wave, (i32(0), i32(0), i32(INF)))
 
-        # in-kernel traceback: the host _traceback's walk, one lane
+        # in-kernel traceback: the XLA program's walk, one lane;
+        # the op path is carried as whole 128-lane vectors and written
+        # at the end
+        oidx = jax.lax.broadcasted_iota(i32, (1, nw_pad), 1)
+
         def tb_cond(st):
-            i, j, cnt, touched = st
+            i, j, cnt, touched, path = st
             return (i > 0) | (j > 0)
 
         def tb_body(st):
-            i, j, cnt, touched = st
+            i, j, cnt, touched, path = st
             d = i + j
             off = offs_ref[0, d]
             k = i - off
@@ -204,31 +230,36 @@ def wavefront_align(edge: int, band: int, score_dtype: str = "int32",
             touched = jnp.where((k >= band - 1)
                                 & (off + band - 1 < row_hi), 1, touched)
             kc = jnp.clip(k, 0, band - 1)
-            code = bps[d, kc].astype(jnp.int32)
+            word = jnp.max(jnp.where(ks == kc,
+                                     bps[pl.ds(d // _BP_PER_WORD, 1), :],
+                                     jnp.iinfo(i32).min))
+            code = (word >> (2 * (d % _BP_PER_WORD))) & 3
             # boundary overrides: on i==0 only D possible; on j==0 only I
             code = jnp.where(i == 0, BP_LEFT, code)
             code = jnp.where(j == 0, BP_UP, code)
             di = jnp.where(code != BP_LEFT, 1, 0)
             dj = jnp.where(code != BP_UP, 1, 0)
-            ops_ref[0, cnt] = code
-            return i - di, j - dj, cnt + 1, touched
+            path = jnp.where(oidx == cnt, code, path)
+            return i - di, j - dj, cnt + 1, touched, path
 
-        i, j, cnt, touched = jax.lax.while_loop(
-            tb_cond, tb_body, (m, n, jnp.int32(0), jnp.int32(0)))
-        meta_ref[0:1, :] = jnp.zeros((1, 128), jnp.int32)
-        meta_ref[0, 0] = cnt
-        meta_ref[0, 1] = dist
-        meta_ref[0, 2] = touched
+        _, _, cnt, touched, path = jax.lax.while_loop(
+            tb_cond, tb_body, (m, n, i32(0), i32(0),
+                               jnp.zeros((1, nw_pad), i32)))
+        ops_ref[...] = path
+        midx = jax.lax.broadcasted_iota(i32, (1, 128), 1)
+        meta_ref[...] = jnp.where(
+            midx == 0, cnt,
+            jnp.where(midx == 1, dist, jnp.where(midx == 2, touched, 0)))
 
     def call(q_ext, t_ext, q_lens, t_lens, offsets):
         B = offsets.shape[0]
         if packed:
             from .encode import PAD, unpack_2bit_jax
 
-            pos_q = jnp.arange(lq, dtype=jnp.int32)[None, :]
-            pos_t = jnp.arange(lt, dtype=jnp.int32)[None, :]
-            ql = q_lens.astype(jnp.int32)[:, None]
-            tl = t_lens.astype(jnp.int32)[:, None]
+            pos_q = jnp.arange(lq, dtype=i32)[None, :]
+            pos_t = jnp.arange(lt, dtype=i32)[None, :]
+            ql = q_lens.astype(i32)[:, None]
+            tl = t_lens.astype(i32)[:, None]
             qx = unpack_2bit_jax(q_ext, lq)
             tx = unpack_2bit_jax(t_ext, lt)
             # PAD restore along the clip maps build_ext baked in:
@@ -241,40 +272,43 @@ def wavefront_align(edge: int, band: int, score_dtype: str = "int32",
                            jnp.int8(PAD), tx)
         else:
             qx, tx = q_ext, t_ext
-        scal = jnp.stack([q_lens.astype(jnp.int32),
-                          t_lens.astype(jnp.int32)], axis=1)      # [B, 2]
-        offs = jnp.pad(offsets.astype(jnp.int32),
-                       ((0, 0), (0, nw_pad - offsets.shape[1])))
-        vmem = pltpu.VMEM
-        return pl.pallas_call(
+        scal = jnp.stack([q_lens.astype(i32), t_lens.astype(i32)],
+                         axis=1).reshape(2 * B)
+        offs = jnp.pad(offsets.astype(i32),
+                       ((0, 0), (0, nw_pad - offsets.shape[1])))[:, None, :]
+        # the rolled windows may read up to BW lanes past the operand's
+        # end; those lanes only ever land outside the band
+        qx = jnp.pad(qx.astype(i32), ((0, 0), (0, LQ - lq)))[:, None, :]
+        tx = jnp.pad(tx.astype(i32), ((0, 0), (0, LT - lt)))[:, None, :]
+        row = lambda b, s: (b, 0, 0)  # noqa: E731
+        ops, meta = pl.pallas_call(
             kernel,
-            grid=(B,),
-            in_specs=[
-                pl.BlockSpec((1, 2), lambda b: (b, 0),
-                             memory_space=pltpu.SMEM),
-                pl.BlockSpec((1, nw_pad), lambda b: (b, 0),
-                             memory_space=vmem),
-                pl.BlockSpec((1, lq), lambda b: (b, 0),
-                             memory_space=vmem),
-                pl.BlockSpec((1, lt), lambda b: (b, 0),
-                             memory_space=vmem),
-            ],
-            out_specs=(
-                pl.BlockSpec((1, nw_pad), lambda b: (b, 0),
-                             memory_space=vmem),
-                pl.BlockSpec((1, 128), lambda b: (b, 0),
-                             memory_space=vmem),
-            ),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(B,),
+                in_specs=[
+                    pl.BlockSpec((None, 1, nw_pad), row,
+                                 memory_space=pltpu.SMEM),
+                    pl.BlockSpec((None, 1, LQ), row),
+                    pl.BlockSpec((None, 1, LT), row),
+                ],
+                out_specs=(
+                    pl.BlockSpec((None, 1, nw_pad), row),
+                    pl.BlockSpec((None, 1, 128), row),
+                ),
+                scratch_shapes=[
+                    pltpu.VMEM((_bp_rows(n_waves), BW), i32),  # bps
+                    pltpu.VMEM((1, BW), i32),      # wavefront d-1
+                    pltpu.VMEM((1, BW), i32),      # wavefront d-2
+                ]),
             out_shape=(
-                jax.ShapeDtypeStruct((B, nw_pad), jnp.int32),
-                jax.ShapeDtypeStruct((B, 128), jnp.int32),
+                jax.ShapeDtypeStruct((B, 1, nw_pad), i32),
+                jax.ShapeDtypeStruct((B, 1, 128), i32),
             ),
-            scratch_shapes=[
-                pltpu.VMEM((1, band), DT),          # wavefront d-1
-                pltpu.VMEM((1, band), DT),          # wavefront d-2
-                pltpu.VMEM((n_waves, band), jnp.int8),  # backpointers
-            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
             interpret=interpret,
-        )(scal, offs, qx.astype(jnp.int32), tx.astype(jnp.int32))
+        )(scal, offs, qx, tx)
+        return ops[:, 0, :], meta[:, 0, :]
 
     return jax.jit(call)

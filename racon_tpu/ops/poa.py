@@ -95,6 +95,13 @@ class BatchPOA:
         # per call.
         self._device_engine = None
         self._session_net = None
+        #: where the last generate_consensus call built each window:
+        #: on the device, on the host because the window is outside the
+        #: device envelope (the reference's per-window GPU->CPU rule),
+        #: on the host for any other reason (host-only run, device
+        #: failure), or backbone-only (too few layers)
+        self.window_counts = dict.fromkeys(
+            ("device", "host_envelope", "host", "backbone"), 0)
 
     #: windows per host batch call (bounds peak packed-buffer memory);
     #: RACON_TPU_HOST_POA_CHUNK overrides it — chunk granularity never
@@ -137,12 +144,14 @@ class BatchPOA:
                                 if self.pipeline is not None else None))
 
     def _generate_consensus(self, windows, trim: bool) -> None:
+        counts = self.window_counts = dict.fromkeys(self.window_counts, 0)
         todo = []
         for w in windows:
             if len(w.sequences) < 3:
                 w.backbone_fallback()
             else:
                 todo.append(w)
+        counts["backbone"] = len(windows) - len(todo)
         if not todo:
             return
 
@@ -174,6 +183,7 @@ class BatchPOA:
                     raise
                 host = degrade(f"{type(exc).__name__}: {exc}")
 
+        counts["host"] = len(host)
         if not host:
             return
         bar = self.logger.bar if self.logger is not None else None
@@ -342,6 +352,10 @@ class BatchPOA:
                      f"{stats['committed']} committed, {stats['redos']} "
                      "banded-clip full-DP retries")
         n_fallback = int((statuses == 1).sum())
+        counts = self.window_counts
+        counts["device"] = int((statuses == 0).sum())
+        counts["host_envelope"] = n_fallback
+        counts["backbone"] += int((statuses == 2).sum())
         if n_fallback:
             # the reference logs GPU-skipped work the same way
             # (cudapolisher.cpp:204-206)

@@ -255,8 +255,6 @@ def fused_raw(n_nodes: int, seq_len: int, depth: int, max_pred: int,
         cand = jnp.where(sinks_r, scores, NEG)
         best_rank = jnp.argmax(cand, axis=1).astype(jnp.int32)
 
-        bp_flat = bps.transpose(1, 0, 2).reshape(B, N * (L + 1))
-        preds_flat = preds_r.reshape(B, N * P)
         rows_b = jnp.arange(B)
 
         def cond(st):
@@ -266,16 +264,15 @@ def fused_raw(n_nodes: int, seq_len: int, depth: int, max_pred: int,
         def body(st):
             r, j, out = st
             active = (r > 0) | (j > 0)
-            lin = (jnp.clip(r - 1, 0, N - 1) * (L + 1) + jnp.clip(j, 0, L))
-            code = jnp.take_along_axis(
-                bp_flat, lin[:, None], axis=1)[:, 0].astype(jnp.int32)
+            # point gathers from the [N, B, L+1] plane, as graph_aligner
+            # does (a batch-major flat copy slows the TPU compile)
+            rc = jnp.clip(r - 1, 0, N - 1)
+            code = bps[rc, rows_b, jnp.clip(j, 0, L)].astype(jnp.int32)
             code = jnp.where(r > 0, code, 2 * P)
             is_diag = code < P
             is_vert = (code >= P) & (code < 2 * P)
             p = jnp.where(is_diag, code, code - P)
-            plin = (jnp.clip(r - 1, 0, N - 1) * P + jnp.clip(p, 0, P - 1))
-            pr = jnp.take_along_axis(preds_flat, plin[:, None],
-                                     axis=1)[:, 0]
+            pr = preds_r[rows_b, rc, jnp.clip(p, 0, P - 1)]
             consume = active & ~is_vert
             jc = jnp.clip(j - 1, 0, L - 1)
             cur = jnp.take_along_axis(out, jc[:, None], axis=1)[:, 0]
@@ -621,7 +618,7 @@ def fused_builder(n_nodes: int, seq_len: int, depth: int, max_pred: int,
     # donate the state buffers on accelerators so chained calls mutate in
     # place instead of allocating a second copy of the graph arrays (the
     # CPU test backend can't donate and would warn on every call)
-    donate = () if jax.default_backend() == "cpu" else tuple(range(11))
+    donate = tuple(range(11)) if jax.default_backend() == "tpu" else ()
     return jax.jit(run, donate_argnums=donate)
 
 

@@ -185,7 +185,7 @@ class BatchRunner:
         if self.sharding is None:
             return fn(*arrays)
         donate = (tuple(donate_argnums)
-                  if jax.default_backend() != "cpu" else ())
+                  if jax.default_backend() == "tpu" else ())
         key = (fn, len(arrays), out_batch_axes, donate)
         shard_fn = self._wrapped.get(key)
         if shard_fn is None:
@@ -199,21 +199,12 @@ class BatchRunner:
                 out_specs = axis_spec(out_batch_axes)
             else:
                 out_specs = tuple(axis_spec(a) for a in out_batch_axes)
-            # check_vma/check_rep off: the kernels mix literal-initialized
-            # and data-derived loop carries, which the varying-axes checker
+            # check_vma off: the kernels mix literal-initialized and
+            # data-derived loop carries, which the varying-axes checker
             # rejects even though every output is plainly batch-sharded
-            kwargs = dict(mesh=self.mesh, in_specs=(spec,) * len(arrays),
-                          out_specs=out_specs)
-            # TypeError covers jax versions where jax.shard_map exists
-            # but takes check_rep instead of check_vma
-            try:
-                smapped = jax.shard_map(fn, check_vma=False, **kwargs)
-            except AttributeError:  # pragma: no cover — older jax
-                from jax.experimental.shard_map import shard_map
-
-                smapped = shard_map(fn, check_rep=False, **kwargs)
-            except TypeError:  # pragma: no cover — check_rep-era jax
-                smapped = jax.shard_map(fn, check_rep=False, **kwargs)
+            smapped = jax.shard_map(fn, mesh=self.mesh,
+                                    in_specs=(spec,) * len(arrays),
+                                    out_specs=out_specs, check_vma=False)
             shard_fn = jax.jit(smapped, donate_argnums=donate)
             self._wrapped[key] = shard_fn
         placed = [jax.device_put(a, self.sharding) for a in arrays]

@@ -27,7 +27,7 @@ ladder is exercisable in CI without real hardware faults:
 
 Strictness: `RACON_TPU_STRICT` / `--tpu-strict` (`strict_mode()`) turns
 every degradation point back into a raise — the bench/CI discipline.
-Decisions key on the error taxonomy in errors.py (DeviceError /
+Decisions key on the error hierarchy in errors.py (DeviceError /
 DeviceTimeout / ChunkCorrupt), never on exception message strings.
 
 With no fault plan and no timeout/retry configuration, every hook in the

@@ -17,7 +17,7 @@ and succeeds — exactly the transient-fault shape the watchdog/retry
 policy (resilience/watchdog.py) is meant to absorb. Persistent failures
 are modelled by arming the same (stage, chunk) several times.
 
-Actions map onto the error taxonomy (errors.py): `raise` -> DeviceError,
+Actions map onto the error hierarchy (errors.py): `raise` -> DeviceError,
 `corrupt` -> ChunkCorrupt (the detected-corruption model: bad data raises
 at the unpack boundary rather than flowing downstream), `hang=<s>` ->
 the call stalls for <s> seconds — under a watchdog deadline that becomes
@@ -27,7 +27,7 @@ a DeviceTimeout; without one the run just finishes late, never deadlocks
 of lingering past the run.
 
 `sdc` is the SILENT-data-corruption model, the one failure the whole
-detected-error taxonomy above cannot represent: a device that computed
+detected-error hierarchy above cannot represent: a device that computed
 WRONG BYTES without tripping any check. A `device:chunk=<N>:sdc` fault
 never raises — `fire()` skips it; instead the consensus engine consumes
 it at the end of its pass (`corrupt_consensus`), flipping one base of

@@ -33,10 +33,12 @@ to XLA's static-shape world. `BatchScheduler` packages the three answers:
      per-engine compile count + seconds, flowing through
      `polisher.occupancy_stats` into bench.py's JSON artifact.
 
-The persistent compile cache (`--tpu-compile-cache DIR` /
-RACON_TPU_COMPILE_CACHE) wires jax's compilation cache
-(`jax_compilation_cache_dir`) so repeated runs — including adaptive-
-ladder runs with data-derived shapes — skip recompiles entirely.
+The persistent compile cache (`enable_compile_cache`) wires jax's
+compilation cache (`jax_compilation_cache_dir`) so repeated runs —
+including adaptive-ladder runs with data-derived shapes — skip
+recompiles entirely. It is placed from outside: JAX_COMPILATION_CACHE_DIR,
+when set, is the only directory; otherwise `--tpu-compile-cache DIR` /
+RACON_TPU_COMPILE_CACHE, else the checkout's own `.jax_cache`.
 
 The scheduler deliberately changes only WHICH static shapes exist and
 HOW jobs are ordered into chunks; chunk dispatch still flows through
@@ -52,7 +54,8 @@ import os
 from .ladder import ladder_1d, ladder_2d, padded_cost_1d, round_up
 from .telemetry import OccupancyStats
 
-__all__ = ["BatchScheduler", "OccupancyStats", "enable_compile_cache",
+__all__ = ["BatchScheduler", "OccupancyStats", "default_cache_dir",
+           "enable_compile_cache",
            "ladder_1d", "ladder_2d", "pack_iteration", "padded_cost_1d",
            "round_up", "shard_interleave"]
 
@@ -113,33 +116,46 @@ def pack_iteration(items: list, cap: int, shape_key, age_key,
             ordered[:start] + ordered[start + size:])
 
 
-def enable_compile_cache(path: str) -> None:
-    """Point jax's persistent compilation cache at `path` (created on
-    first write). Idempotent; also exported via the environment so bench
-    subprocesses and wrapper children inherit it. The min-compile-time
-    threshold is dropped to 0 so even fast-compiling shapes (small CPU
-    test kernels, warm-run probes) persist — the cache exists to make
-    the SECOND run cheap, whatever the first cost."""
-    path = os.path.abspath(path)
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = path
+#: the checkout root — the compile cache's fixed home when nothing else
+#: places it (a path that moves between runs never hits)
+_REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def default_cache_dir() -> str:
+    """The compile-cache directory in force when no option names one:
+    JAX_COMPILATION_CACHE_DIR if set, else `<repo>/.jax_cache`."""
+    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            or os.path.join(_REPO, ".jax_cache"))
+
+
+def enable_compile_cache(path: str | None = None) -> str:
+    """Point jax's persistent compilation cache at its directory and
+    return it. JAX_COMPILATION_CACHE_DIR, when set, is the only
+    directory and `path` yields to it; otherwise `path` (the
+    --tpu-compile-cache / RACON_TPU_COMPILE_CACHE choice), else
+    `<repo>/.jax_cache`. Idempotent; exported via the environment so
+    child processes inherit it. The min-compile-time threshold is
+    dropped to 0 so even fast-compiling shapes persist — the cache
+    exists to make the SECOND run cheap, whatever the first cost."""
     import jax
 
+    path = os.path.abspath(os.environ.get("JAX_COMPILATION_CACHE_DIR")
+                           or path or default_cache_dir())
+    if jax.config.jax_compilation_cache_dir == path:
+        return path
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = path
     jax.config.update("jax_compilation_cache_dir", path)
-    for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                      ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(knob, val)
-        except (AttributeError, ValueError):  # older jax: knob absent
-            pass
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     # jax memoizes the cache object on first use: a process that already
     # compiled something (e.g. the CLI redirecting mid-init) needs the
     # memo dropped so the new directory actually takes effect
-    try:
-        from jax._src.compilation_cache import reset_cache
+    from jax.experimental.compilation_cache.compilation_cache import \
+        reset_cache
 
-        reset_cache()
-    except Exception:
-        pass
+    reset_cache()
+    return path
 
 
 class BatchScheduler:
@@ -163,7 +179,8 @@ class BatchScheduler:
                  compile_cache: str | None = None) -> "BatchScheduler":
         """Build from the environment posture. Explicit arguments (the
         CLI flags) win over RACON_TPU_ADAPTIVE_BUCKETS /
-        RACON_TPU_COMPILE_CACHE."""
+        RACON_TPU_COMPILE_CACHE; either cache choice yields to
+        JAX_COMPILATION_CACHE_DIR (enable_compile_cache)."""
         if adaptive is None:
             adaptive = bool(os.environ.get("RACON_TPU_ADAPTIVE_BUCKETS"))
         cache = compile_cache or os.environ.get("RACON_TPU_COMPILE_CACHE")

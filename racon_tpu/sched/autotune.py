@@ -1,24 +1,23 @@
 """Persisted per-bucket kernel autotuner: profile once, dispatch forever.
 
-tpu_smoke.py's PALLAS_PROFILE step has measured XLA-vs-Pallas per bucket
-since round 4, but the numbers only ever reached stderr — every run
-re-decided the kernel plane from a static env flag. This library makes
-the measurement durable and load-bearing:
+`profile_production` measures XLA-vs-Pallas (and int32-vs-int16) for
+every production bucket on the live backend; this library makes the
+measurement durable and load-bearing:
 
   - `Autotuner.profile_session_bucket` / `profile_aligner_bucket` time
     the candidate programs for one bucket on the LIVE backend (XLA scan
     vs Pallas resident kernel, int32 vs envelope-proof int16), verify
     the candidates agree bit-for-bit on synthetic jobs, and record the
     fastest (kernel, dtype) pair;
-  - the winner table persists as JSON next to the XLA compile cache
-    (RACON_TPU_AUTOTUNE_CACHE, else `<compile cache>/{BASENAME}`, else
-    `~/.cache/racon_tpu/{BASENAME}`), keyed by (backend, engine, bucket
+  - the winner table persists as JSON next to the XLA compile cache in
+    force (RACON_TPU_AUTOTUNE_CACHE, else `<compile cache>/{BASENAME}`,
+    see sched.enable_compile_cache), keyed by (backend, engine, bucket
     shape, score params) — a table profiled on chip never leaks into a
     CPU run and vice versa;
   - under RACON_TPU_PALLAS=auto all three engine dispatchers
     (`BatchAligner`, `DeviceGraphPOA`, `FusedPOA`) consult the table
-    per bucket via `winner()`: profile once (tpu_smoke, or any explicit
-    profile call), then every warm serve job and CLI run dispatches the
+    per bucket via `winner()`: profile once (`profile_production`, or any
+    explicit profile call), then every warm serve job and CLI run dispatches the
     measured winner. A cold run without a table dispatches the XLA
     programs exactly as today.
 
@@ -59,12 +58,12 @@ def default_table_path() -> str:
     explicit = os.environ.get("RACON_TPU_AUTOTUNE_CACHE")
     if explicit:
         return explicit
-    cache = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
-             or os.environ.get("RACON_TPU_COMPILE_CACHE"))
-    if cache:
-        return os.path.join(cache, BASENAME)
-    return os.path.join(os.path.expanduser("~/.cache/racon_tpu"),
-                        BASENAME)
+    import jax
+
+    from . import default_cache_dir
+
+    return os.path.join(jax.config.jax_compilation_cache_dir
+                        or default_cache_dir(), BASENAME)
 
 
 def _backend() -> str:
@@ -282,7 +281,7 @@ class Autotuner:
         dtypes = ["int32"]
         if poa_int16_ok(n_nodes, seq_len, match, mismatch, gap):
             dtypes.append("int16")
-        interp = _backend() == "cpu"
+        interp = _backend() != "tpu"
 
         ms: dict[str, float] = {}
         outs: dict[str, np.ndarray] = {}
@@ -315,8 +314,7 @@ class Autotuner:
         so a candidate that gets only the path right must still be
         vetoed."""
         from ..ops import align_pallas
-        from ..ops.align import (_kernel_for, _runs_of, _traceback,
-                                 _unpack_bp, band_offsets)
+        from ..ops.align import _kernel_for, band_offsets, decode_paths
         from ..ops.dtypes import aligner_int16_ok
         from ..ops.encode import encode_padded
 
@@ -335,7 +333,7 @@ class Autotuner:
         dtypes = ["int32"]
         if aligner_int16_ok(edge):
             dtypes.append("int16")
-        interp = _backend() == "cpu"
+        interp = _backend() != "tpu"
 
         # distances compare normalized: the sentinel magnitude differs
         # per dtype (1<<28 vs 1<<14) but both mean "never reached (M,N)"
@@ -343,29 +341,27 @@ class Autotuner:
             return ["inf" if v >= (1 << 14) else int(v)
                     for v in np.asarray(d).astype(np.int64)]
 
+        def _decoded(op_arr, meta):
+            meta = np.asarray(meta)
+            return (decode_paths(op_arr, meta[:, 0]),
+                    [bool(t) for t in meta[:, 2] > 0],
+                    _dist_norm(meta[:, 1]))
+
         ms: dict[str, float] = {}
         outs: dict[str, tuple] = {}
         for dt in dtypes:
             fn = _kernel_for(band, n_waves, dt, False)
             ms[f"xla:{dt}"], out = self._time(
                 fn, (q_arr, t_arr, ql32, tl32, offs), reps)
-            bp = _unpack_bp(np.asarray(out[0]))
-            runs, touched = _traceback(bp, offs, q_lens, t_lens)
-            outs[f"xla:{dt}"] = (runs, [bool(t) for t in touched],
-                                 _dist_norm(out[1]))
+            outs[f"xla:{dt}"] = _decoded(np.asarray(out[0]).T, out[1])
             if align_pallas.fits_vmem(edge, band, dt):
                 pfn = align_pallas.wavefront_align(edge, band, dt, False,
                                                    interpret=interp)
                 qx, tx = align_pallas.build_ext(q_arr, t_arr, band)
                 ms[f"pallas:{dt}"], pout = self._time(
                     pfn, (qx, tx, ql32, tl32, offs), reps)
-                op_arr = np.asarray(pout[0])
-                meta = np.asarray(pout[1])
-                outs[f"pallas:{dt}"] = (
-                    [_runs_of(op_arr[k, :meta[k, 0]][::-1])
-                     for k in range(len(pairs))],
-                    [bool(t) for t in meta[:, 2] > 0],
-                    _dist_norm(meta[:, 1]))
+                outs[f"pallas:{dt}"] = _decoded(np.asarray(pout[0]),
+                                                pout[1])
         entry = self._pick(ms, outs, "xla:int32")
         self.record("aligner", (edge, band), (), entry)
         return entry, True
@@ -460,8 +456,7 @@ class Autotuner:
 def _session_jobs(n_nodes: int, seq_len: int, max_pred: int, rows: int,
                   seed: int):
     """Linear-chain POA jobs (sequence-as-graph + a deletion-bearing
-    layer), densified exactly the way the C++ session does — the same
-    synthetic shape tpu_smoke has always profiled with."""
+    layer), densified exactly the way the C++ session does."""
     rng = np.random.default_rng(seed)
     codes = np.full((rows, n_nodes), 5, dtype=np.int8)
     preds = np.full((rows, n_nodes, max_pred), -1, dtype=np.int16)
@@ -540,3 +535,47 @@ def get_autotuner() -> Autotuner:
 def reset_autotuner_cache() -> None:
     """Drop the process cache (tests that rewrite the table on disk)."""
     _cached.clear()
+
+
+def profile_production(at: Autotuner | None = None, log=print) -> str:
+    """Profile every production bucket at the keys the default-built
+    dispatchers consult under `auto`, then save the table (returns its
+    path). Buckets already in the table are not re-timed.
+
+    - session buckets: every `poa_graph.BUCKETS` entry at the polisher/
+      CLI default scoring (3, -5, -4) and the engine's MAX_PRED — the
+      exact `DeviceGraphPOA._plan` lookup;
+    - aligner buckets: every band the auto rule can dispatch per edge
+      (`BatchAligner._band_for` quantizes 10% of the bucket's mean pair
+      length up to a multiple of 128, so edge `e` requests a band in
+      128..round128(e * 0.1));
+    - fused-loop buckets: split chained vs single-launch fused dispatch
+      per depth bucket at (env_max_nodes(), MAX_LEN), the key
+      `FusedPOA._fused_plan` consults."""
+    from ..ops.poa_fused import DEPTH_BUCKETS
+    from ..ops.poa_graph import BUCKETS, MAX_LEN, MAX_PRED, env_max_nodes
+
+    at = at if at is not None else Autotuner()
+
+    def report(name, key, ent, fresh):
+        log(f"{name} {key}: winner {ent['kernel']}:{ent['dtype']} "
+            f"identical={ent['identical']} "
+            f"fresh={'yes' if fresh else 'no'} ms={ent['ms']}")
+
+    for nb, lb in BUCKETS:
+        ent, fresh = at.profile_session_bucket(nb, lb, MAX_PRED, 3, -5, -4,
+                                               rows=32)
+        report("session", (nb, lb), ent, fresh)
+    for edge in (512, 1024, 2048, 4096):
+        top = max(128, (int(edge * 0.1) + 127) // 128 * 128)
+        for band in range(128, top + 128, 128):
+            ent, fresh = at.profile_aligner_bucket(edge, band)
+            report("aligner", (edge, band), ent, fresh)
+    n = env_max_nodes()
+    for d in DEPTH_BUCKETS:
+        ent, fresh = at.profile_fused_bucket(n, MAX_LEN, d, MAX_PRED,
+                                             3, -5, -4)
+        report("fused_loop", (n, MAX_LEN, d), ent, fresh)
+    path = at.save()
+    log(f"winner table ({len(at.table)} entries) -> {path}")
+    return path

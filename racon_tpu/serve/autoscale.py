@@ -12,7 +12,9 @@ requeue). The autoscaler just connects signal to action:
     routable replica) stays above ``up_pressure`` for ``up_sustain_s``
     seconds — or the deadline burn-rate alert is firing — and the fleet
     is below ``max_replicas``, spawn one warm replica subprocess
-    (``racon_tpu serve --socket <dir>/autoscale_<n>.sock``), wait for
+    (``racon_tpu serve --socket <dir>/autoscale_<n>.sock``) — on a chip
+    host confined to a chip no other spawned replica holds, or refused
+    (`NoFreeChip`, counted under ``spawn_failures``) — wait for
     its first clean healthz, and join it to the routing set: rejoin is
     instant because the router routes on healthz, not on config.
   - **Scale-down.** When the fleet has been fully idle (zero backlog,
@@ -57,7 +59,9 @@ tears it down on drain). Tests drive `step()` directly with injected
 from __future__ import annotations
 
 import contextlib
+import glob
 import os
+import re
 import subprocess
 import sys
 import tempfile
@@ -157,13 +161,41 @@ class AutoscaleConfig:
                 f"unknown autoscale option(s): {', '.join(sorted(kw))}")
 
 
-def _default_spawn(spec: str):
+class NoFreeChip(RaconError):
+    """A scale-up found every chip on this host already serving a
+    replica this loop spawned."""
+
+
+def host_chips() -> list[int]:
+    """Indices of this host's TPU chips, read from the accelerator
+    device nodes a TPU VM exposes — never through JAX: a router that
+    loaded the TPU runtime would hold every chip its replicas need.
+    Empty on a host without chips."""
+    found = (re.fullmatch(r"/dev/accel(\d+)", p)
+             for p in glob.glob("/dev/accel*"))
+    return sorted(int(m.group(1)) for m in found if m)
+
+
+def chip_env(chip: int) -> dict:
+    """Environment that confines one replica process to chip `chip`
+    (one process per chip, each with its own runtime port)."""
+    return {"TPU_VISIBLE_CHIPS": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_PORT": str(8476 + chip)}
+
+
+def _default_spawn(spec: str, chip: int | None = None):
     """Spawn one warm replica subprocess serving on `spec` (unix
-    socket). The child inherits the environment, so the operator's
-    RACON_TPU_SERVE_* posture applies to scaled-up replicas too."""
-    return subprocess.Popen(
+    socket), confined to `chip` on a chip host. The child inherits the
+    environment, so the operator's RACON_TPU_SERVE_* posture applies to
+    scaled-up replicas too."""
+    env = None if chip is None else dict(os.environ, **chip_env(chip))
+    handle = subprocess.Popen(
         [sys.executable, "-m", "racon_tpu", "serve", "--socket", spec],
-        stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
+        stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL, env=env)
+    handle.chip = chip
+    return handle
 
 
 def _default_stop(handle) -> None:
@@ -186,11 +218,14 @@ class Autoscaler:
     whole decision function, drivable without the thread."""
 
     def __init__(self, router, config: AutoscaleConfig | None = None,
-                 spawn=None, stop=None, **overrides):
+                 spawn=None, stop=None, chips=None, **overrides):
         self.router = router
         self.config = config if config is not None \
             else AutoscaleConfig(**overrides)
-        self._spawn = spawn or _default_spawn
+        self._spawn = spawn or self._spawn_on_chip
+        #: chips the default spawn hands out, one replica each (empty:
+        #: no chips on this host, replicas are not pinned)
+        self._chips = host_chips() if chips is None else list(chips)
         self._stop_replica = stop or _default_stop
         self._dir = self.config.socket_dir or tempfile.mkdtemp(
             prefix="racon_tpu_autoscale_")
@@ -316,6 +351,21 @@ class Autoscaler:
         return None
 
     # --------------------------------------------------------- actions
+    def _spawn_on_chip(self, spec: str):
+        """The default spawn: on a chip host, a replica gets a chip no
+        replica of this loop holds, or the spawn is refused."""
+        if not self._chips:
+            return _default_spawn(spec)
+        with self._lock:
+            held = {getattr(e["handle"], "chip", None)
+                    for e in self.spawned}
+        free = [c for c in self._chips if c not in held]
+        if not free:
+            raise NoFreeChip("autoscale",
+                             f"all {len(self._chips)} chips on this host "
+                             "already serve a replica")
+        return _default_spawn(spec, chip=free[0])
+
     def _scale_up(self, reason: str, pressure: float) -> bool:
         with self._lock:
             self._seq += 1

@@ -4,7 +4,7 @@ One request = one connection (the server multiplexes concurrency across
 connections, so a client that wants N jobs in flight opens N sockets —
 exactly what `tools/servebench.py` does from a thread pool). Errors come
 back as the protocol's typed error responses and are re-raised as the
-exception taxonomy below, so callers branch on types, not message
+exception hierarchy below, so callers branch on types, not message
 strings:
 
     QueueFull       admission control rejected; `retry_after` seconds
@@ -194,7 +194,7 @@ class PolishClient:
 
     def request(self, obj: dict, on_progress=None, on_part=None,
                 recorder=None) -> dict:
-        """One round trip; raises the ServeError taxonomy on a typed
+        """One round trip; raises the ServeError hierarchy on a typed
         error response. Interleaved `progress` frames (a `submit` with
         "progress": true) are handed to `on_progress` as they arrive,
         and streamed `result_part` frames (a `submit` with "stream":

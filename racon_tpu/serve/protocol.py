@@ -33,7 +33,7 @@ RPC twin of the HTTP endpoint's 503) / ``metrics`` (Prometheus text in
 windowed to one trace id) / ``ok`` / ``error`` (with a
 machine-readable ``code``; ``queue-full`` errors carry ``retry_after``
 seconds, ``job-failed`` errors carry ``error_type`` from the errors.py
-taxonomy).
+hierarchy).
 
 Cancellation & QoS (README "QoS & preemption"): a ``cancel`` request
 carries ``job_id`` and/or ``trace_id`` and answers ``{"type": "ok",
@@ -284,7 +284,7 @@ def send_frame(sock: socket.socket, obj: dict) -> None:
 def recv_frame(sock: socket.socket,
                max_frame: int | None = None) -> dict | None:
     """Read one frame; None on clean EOF (peer closed between frames).
-    Raises the ProtocolError taxonomy above on malformed input."""
+    Raises the ProtocolError hierarchy above on malformed input."""
     limit = max_frame if max_frame is not None else max_frame_bytes()
     header = _recv_exact(sock, _HEADER.size)
     if not header:

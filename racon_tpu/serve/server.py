@@ -2625,6 +2625,13 @@ def serve_main(argv: list[str]) -> int:
     ap.add_argument("--tpu-adaptive-buckets", action="store_true")
     ap.add_argument("--tpu-compile-cache", default=None)
     args = ap.parse_args(argv)
+    if args.tpupoa_batches > 0 or args.tpualigner_batches > 0:
+        from ..sched import enable_compile_cache
+
+        # a served process always keeps its compiles (the placement
+        # rule: JAX_COMPILATION_CACHE_DIR, else the option, else the
+        # checkout's .jax_cache)
+        args.tpu_compile_cache = enable_compile_cache(args.tpu_compile_cache)
 
     kw: dict = {
         "warmup": not args.no_warmup,

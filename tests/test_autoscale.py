@@ -192,6 +192,39 @@ def test_spawn_failure_counts_and_never_routes(tmp_path, monkeypatch):
     assert spawned and stopped == spawned and router.added == []
 
 
+def test_default_spawn_gives_each_replica_its_own_chip(tmp_path,
+                                                      monkeypatch):
+    """On a chip host every spawned replica is confined to a chip no
+    other replica of the loop holds; with none free the spawn is refused
+    with a typed error and counted, never started."""
+    from racon_tpu.serve import autoscale
+
+    started: list[tuple[str, int | None]] = []
+
+    def fake_spawn(spec, chip=None):
+        started.append((spec, chip))
+        return types.SimpleNamespace(chip=chip)
+
+    monkeypatch.setattr(autoscale, "_default_spawn", fake_spawn)
+    monkeypatch.setattr(Autoscaler, "_wait_ready", lambda self, spec: True)
+    router = _Router(n=1)
+    sc = Autoscaler(router, AutoscaleConfig(
+        min_replicas=1, max_replicas=4, cooldown_s=0.0, interval_s=999.0,
+        socket_dir=str(tmp_path)), stop=lambda h: None, chips=[0, 1])
+    assert sc._scale_up("pressure", 9.0) and sc._scale_up("pressure", 9.0)
+    assert [c for _, c in started] == [0, 1]
+    with pytest.raises(autoscale.NoFreeChip):
+        sc._spawn_on_chip(str(tmp_path / "x.sock"))
+    assert not sc._scale_up("pressure", 9.0)
+    assert sc.counters["spawn_failures"] == 1 and len(started) == 2
+    assert autoscale.chip_env(1)["TPU_VISIBLE_CHIPS"] == "1"
+    # a host without chips spawns unpinned
+    sc2 = Autoscaler(_Router(n=1), AutoscaleConfig(
+        min_replicas=1, max_replicas=4, interval_s=999.0,
+        socket_dir=str(tmp_path)), stop=lambda h: None, chips=[])
+    assert sc2._scale_up("pressure", 9.0) and started[-1][1] is None
+
+
 # ----------------------------------------------------------- scale down
 def test_scale_down_unroutes_before_stopping(tmp_path, monkeypatch):
     router = _Router(n=1)

@@ -174,8 +174,8 @@ def test_fragment_correction_with_qualities_full():
 
 
 # -- whole-output golden diff (ci/gpu/cuda_test.sh:30-44 analogue) --------
-# the committed file is regenerated only by tools/make_golden.py; both
-# engines must reproduce it byte-for-byte
+# both engines must reproduce the committed files byte-for-byte; the
+# synthetic one is regenerated by tools/make_golden.py
 
 GOLDEN = os.path.join(os.path.dirname(__file__), "data",
                       "sample_golden.fasta")

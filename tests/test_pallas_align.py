@@ -18,8 +18,8 @@ import pytest
 jax = pytest.importorskip("jax")
 
 from racon_tpu.ops import align_pallas
-from racon_tpu.ops.align import (BatchAligner, _kernel_for, _runs_of,
-                                 _traceback, _unpack_bp, band_offsets)
+from racon_tpu.ops.align import (BatchAligner, _kernel_for, band_offsets,
+                                 decode_paths)
 from racon_tpu.ops.dtypes import (aligner_int16_ok, dtype_mode,
                                   poa_int16_ok, resolve_dtype)
 from racon_tpu.ops.encode import (encode_padded, pack_2bit, packable,
@@ -46,7 +46,7 @@ def _mutate(rng, s, rate):
 
 
 def _xla_decode(pairs, edge, band, dtype="int32"):
-    """The XLA reference path: kernel -> host traceback -> (runs,
+    """The XLA reference path: kernel + device traceback -> (runs,
     touched, dist)."""
     n_waves = 2 * edge + 1
     q_arr, q_lens = encode_padded([p[0] for p in pairs], edge)
@@ -54,11 +54,11 @@ def _xla_decode(pairs, edge, band, dtype="int32"):
     offs = np.stack([band_offsets(int(ql), int(tl), band, n_waves)
                      for ql, tl in zip(q_lens, t_lens)])
     fn = _kernel_for(band, n_waves, dtype, False)
-    bp, dist = fn(q_arr, t_arr, q_lens.astype(np.int32),
-                  t_lens.astype(np.int32), offs)
-    runs, touched = _traceback(_unpack_bp(np.asarray(bp)), offs,
-                               q_lens, t_lens)
-    return (runs, touched, np.asarray(dist).astype(np.int64),
+    ops, meta = fn(q_arr, t_arr, q_lens.astype(np.int32),
+                   t_lens.astype(np.int32), offs)
+    meta = np.asarray(meta)
+    runs = decode_paths(np.asarray(ops).T, meta[:, 0])
+    return (runs, meta[:, 2] > 0, meta[:, 1].astype(np.int64),
             (q_arr, t_arr, q_lens, t_lens, offs))
 
 
@@ -71,10 +71,8 @@ def _pallas_decode(operands, edge, band, dtype, packed):
         qx, tx = pack_2bit(qx), pack_2bit(tx)
     ops, meta = fn(qx, tx, q_lens.astype(np.int32),
                    t_lens.astype(np.int32), offs)
-    ops = np.asarray(ops)
     meta = np.asarray(meta)
-    runs = [_runs_of(ops[k, :meta[k, 0]][::-1])
-            for k in range(len(q_lens))]
+    runs = decode_paths(np.asarray(ops), meta[:, 0])
     return runs, meta[:, 2] > 0, meta[:, 1].astype(np.int64)
 
 
@@ -144,9 +142,10 @@ def test_int16_envelope_predicates():
 
 
 def test_int16_bitwise_identical_at_max_cost():
-    """int16 vs int32 XLA kernels: RAW outputs (packed backpointers and
-    distances) must be bit-identical, including the worst-cost pair the
-    bucket can hold (cost == edge, the envelope's score ceiling)."""
+    """int16 vs int32 XLA kernels: RAW outputs (paths, path lengths,
+    distances, edge flags) must be bit-identical, including the
+    worst-cost pair the bucket can hold (cost == edge, the envelope's
+    score ceiling)."""
     edge, band = 512, 64
     rng = random.Random(3)
     t = bytes(rng.choice(ACGT) for _ in range(edge))
@@ -158,14 +157,14 @@ def test_int16_bitwise_identical_at_max_cost():
                      for ql, tl in zip(q_lens, t_lens)])
     outs = {}
     for dt in ("int32", "int16"):
-        bp, dist = _kernel_for(band, n_waves, dt, False)(
+        ops, meta = _kernel_for(band, n_waves, dt, False)(
             q_arr, t_arr, q_lens.astype(np.int32),
             t_lens.astype(np.int32), offs)
-        outs[dt] = (np.asarray(bp), np.asarray(dist).astype(np.int64))
+        outs[dt] = (np.asarray(ops), np.asarray(meta))
     np.testing.assert_array_equal(outs["int32"][0], outs["int16"][0])
     # finite distances equal; sentinel distances (none here) aside
     np.testing.assert_array_equal(outs["int32"][1], outs["int16"][1])
-    assert outs["int32"][1][0] == edge  # the ceiling really was hit
+    assert outs["int32"][1][0, 1] == edge  # the ceiling really was hit
 
 
 def test_packed_encode_roundtrip():

@@ -371,7 +371,13 @@ def test_enable_compile_cache_configures_jax(tmp_path, monkeypatch):
     prev = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     cache = tmp_path / "xla-cache"
     try:
-        enable_compile_cache(str(cache))
+        # an operator's JAX_COMPILATION_CACHE_DIR is the only directory:
+        # the option yields to it
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "op"))
+        assert enable_compile_cache(str(cache)) == str(tmp_path / "op")
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path / "op")
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache(str(cache)) == str(cache)
         assert os.environ["JAX_COMPILATION_CACHE_DIR"] == str(cache)
         assert jax.config.jax_compilation_cache_dir == str(cache)
 
@@ -388,7 +394,8 @@ def test_enable_compile_cache_configures_jax(tmp_path, monkeypatch):
         # restore: the suite's shared persistent cache must keep working
         # for the tests that follow
         if prev is not None:
-            enable_compile_cache(prev)
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", prev)
+            enable_compile_cache()
 
 
 def test_scheduler_from_env(monkeypatch):
