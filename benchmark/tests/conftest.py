@@ -1,0 +1,13 @@
+"""CPU checks of the benchmark: run with `python -m pytest benchmark/tests`
+from the repository root. The harness's modules are imported from
+`benchmark/`; JAX is held to the CPU."""
+
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH)
+for p in (ROOT, BENCH):
+    if p not in sys.path:
+        sys.path.insert(0, p)
