@@ -211,8 +211,9 @@ usage: racon_tpu [options ...] <sequences> <overlaps> <target sequences>
             deduplicated per-chunk warning (mirrors RACON_TPU_LOG_LEVEL)
         --tpu-jax-profile <dir>
             default: none
-            bracket the device phases with a jax.profiler capture into
-            <dir> (deep-dive XLA/TPU view; no-op when the backend cannot
+            one jax.profiler capture of the whole run into <dir>: the
+            device tracks and the racon.* host spans on one timeline
+            (deep-dive XLA/TPU view; no-op when the backend cannot
             profile; mirrors RACON_TPU_PROFILE)
         --tpualigner-batches <int>
             default: 0
@@ -468,7 +469,7 @@ def main(argv: list[str] | None = None) -> int:
         return 1
 
     from .core.polisher import create_polisher, PolisherType
-    from .obs import trace
+    from .obs import jax_profile, trace
     from .utils.logger import set_log_level
 
     import os
@@ -532,8 +533,9 @@ def main(argv: list[str] | None = None) -> int:
             opts["tpu_engine"], opts["tpu_pipeline_depth"],
             opts["tpu_device_timeout"], opts["tpu_adaptive_buckets"],
             opts["tpu_compile_cache"])
-        polisher.initialize()
-        polished = polisher.polish(opts["drop_unpolished_sequences"])
+        with jax_profile():
+            polisher.initialize()
+            polished = polisher.polish(opts["drop_unpolished_sequences"])
 
         out = sys.stdout.buffer
         for seq in polished:

@@ -3,11 +3,14 @@
 Three pillars, all off (or invisible) by default so the clean run's
 output and stderr stay byte-identical:
 
-  1. SPAN TRACING (`obs.trace`): a thread-safe `TraceRecorder` armed by
-     RACON_TPU_TRACE=<out.json> / `--tpu-trace`, emitting Chrome
-     trace-event JSON for Perfetto — per-chunk pipeline stage spans,
-     engine dispatch loops, XLA compiles, watchdog backoff, and instant
-     events mirroring every resilience counter bump.
+  1. SPAN TRACING (`obs.trace`): one span idiom, `trace.span()`, for
+     the polisher's phases and their parts, per-chunk pipeline stages,
+     engine dispatch loops and watchdog backoff. Its sinks: a
+     thread-safe `TraceRecorder` armed by RACON_TPU_TRACE=<out.json> /
+     `--tpu-trace`, emitting Chrome trace-event JSON for Perfetto (with
+     XLA compiles and instant events mirroring every resilience counter
+     bump), and any running JAX profiler capture, as `racon.*`
+     annotations on the device trace's clock.
   2. METRICS REGISTRY (`obs.metrics.MetricsRegistry`): the pipeline /
      sched / resilience telemetry islands consolidated into one
      namespaced snapshot — bench JSON `"metrics"` field, `--tpu-metrics
@@ -16,10 +19,11 @@ output and stderr stay byte-identical:
      RACON_TPU_LOG_LEVEL=quiet|info|debug structured stderr logging
      with once-per-run deduplication of repeated per-chunk warnings.
 
-`jax_profile(phase)` is the optional deep-dive hook: a context manager
-bracketing a device phase with `jax.profiler` when RACON_TPU_PROFILE /
-`--tpu-jax-profile <dir>` names a directory, and a silent no-op when the
-profiler is unavailable on the backend.
+`jax_profile()` is the optional deep-dive hook: one `jax.profiler`
+capture around a whole run when RACON_TPU_PROFILE / `--tpu-jax-profile
+<dir>` names a directory, holding the device tracks and, on the same
+clock, every `racon.*` span the run opens (`obs.trace`); a silent
+no-op when the profiler is unavailable on the backend.
 
 The serve-grade additions (PR 6) build on the same pillars:
 
@@ -71,7 +75,7 @@ class _SafeJaxProfile:
             if self._strict:
                 raise
             log_debug(f"[racon_tpu::obs] jax profiler unavailable "
-                      f"({type(exc).__name__}: {exc}); phase runs "
+                      f"({type(exc).__name__}: {exc}); run goes "
                       "unprofiled")
             self._cm = None
         return self
@@ -88,14 +92,14 @@ class _SafeJaxProfile:
         return False
 
 
-def jax_profile(phase: str = ""):
-    """Context manager bracketing one device phase with a jax.profiler
-    trace under RACON_TPU_PROFILE/<phase> (each phase gets its own
-    capture directory so align and consensus don't clobber each other).
-    A no-op context when the knob is unset."""
+def jax_profile():
+    """Context manager bracketing a whole run with one jax.profiler
+    capture into RACON_TPU_PROFILE: parsing, alignment, consensus and
+    the stitch with their `racon.*` spans beside the device tracks. A
+    no-op context when the knob is unset."""
     import contextlib
 
     base = os.environ.get("RACON_TPU_PROFILE")
     if not base:
         return contextlib.nullcontext()
-    return _SafeJaxProfile(os.path.join(base, phase) if phase else base)
+    return _SafeJaxProfile(base)

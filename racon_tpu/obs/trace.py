@@ -1,29 +1,38 @@
-"""Span tracing: Chrome trace-event recording for Perfetto.
+"""Span tracing: the program's one span idiom.
 
 The reference exposes only coarse phase timings (src/logger.cpp); a slow
-or degraded run gives no way to see WHERE the time went. `TraceRecorder`
-records per-event spans — pipeline pack/device/unpack/fallback stages
-per chunk, engine dispatch loops, XLA compiles, watchdog backoff — plus
-instant events for every resilience counter bump (faults, retries,
-timeouts, breaker trips, quarantined windows, cancelled futures), and
-writes them as Chrome trace-event JSON loadable in Perfetto
-(https://ui.perfetto.dev) or chrome://tracing.
+or degraded run gives no way to see WHERE the time went. `span(name,
+**args)` is a context that times one piece of work where it happens —
+pipeline pack/device/unpack/fallback stages per chunk, the polisher's
+phases and their parts, engine dispatch loops, watchdog backoff — and
+hands it to two sinks:
+
+  * the Chrome recorder (`TraceRecorder`), armed by
+    RACON_TPU_TRACE=<out.json> (mirrored by the CLI's `--tpu-trace`),
+    an explicit `configure()`, or the serve layer's flight ring. It
+    also keeps instant events for every resilience counter bump and
+    writes Chrome trace-event JSON loadable in Perfetto
+    (https://ui.perfetto.dev) or chrome://tracing;
+  * a running JAX profiler capture (`--tpu-jax-profile`, or any
+    `jax.profiler.trace` around the run), where the span is a
+    `jax.profiler.TraceAnnotation` named `racon.<name>` with the same
+    arguments — on the capture's own clock, beside the device tracks.
 
 Design constraints, in order:
 
-  1. OFF BY DEFAULT, zero overhead when off. The process-wide tracer is
-     armed only by RACON_TPU_TRACE=<out.json> (mirrored by the CLI's
-     `--tpu-trace`) or an explicit `configure()`; every hot-path hook is
-     an `is None` check against the resolved-once singleton.
+  1. OFF BY DEFAULT, near-zero overhead when off: with no recorder and
+     no capture, `span()` returns the shared null span after one
+     `is None` check and one `TraceAnnotation.is_enabled()` call. The
+     capture itself is the switch for the profiler sink.
   2. Low overhead when ON: events append to per-thread buffers (no lock
      on the hot path — each pipeline worker owns its list; the shared
      lock is taken once per thread, at buffer registration), timestamps
-     come from the monotonic `time.perf_counter` clock the pipeline's
-     stage counters already use, and serialization happens once, at
-     `save()`. Instrumentation sites reuse the exact perf_counter
-     endpoints they feed into PipelineStats, so per-stage span-duration
-     sums equal the stage wall-clock counters by construction
-     (pinned by tests/test_obs.py).
+     come from the monotonic `time.perf_counter` clock, and
+     serialization happens once, at `save()`. Sites that charge a
+     counter open their span with `timed()` and charge it from the
+     span's own `t0`/`t1`, so per-stage span-duration sums equal the
+     stage wall-clock counters by construction (pinned by
+     tests/test_obs.py).
   3. Thread-safe: concurrent pipeline threads (pack worker, dispatcher,
      unpack worker, fallback pool, watchdog workers) record freely;
      `events()` snapshots every buffer and sorts by timestamp.
@@ -33,31 +42,78 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 
+#: prefix of every span's name inside a JAX profiler capture
+CAPTURE_PREFIX = "racon."
+
+# the annotation class, resolved on the first span after jax is
+# imported: no capture can run before that, and this module must not
+# import jax itself (the serve client and the tools load it without)
+_annotation_cls = None
+# per-thread stack of the spans open in a capture (for tag())
+_open = threading.local()
+
+
+def capturing() -> bool:
+    """Whether a JAX profiler capture is running in this process."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        if "jax" not in sys.modules:
+            return False
+        from jax.profiler import TraceAnnotation
+
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls.is_enabled()
+
 
 class _Span:
-    """Context manager recording one complete ("X") event on exit."""
+    """One live span. Records its own `time.perf_counter` endpoints
+    (`t0`, `t1`); on exit it feeds the armed recorder (if any), and
+    while a profiler capture runs it is also the annotation
+    `racon.<name>`. A span that exits by an exception is still
+    recorded: the time went somewhere."""
 
-    __slots__ = ("_rec", "_name", "_args", "_t0")
+    __slots__ = ("_rec", "name", "args", "_ann", "t0", "t1")
 
-    def __init__(self, rec: "TraceRecorder", name: str, args: dict | None):
+    def __init__(self, rec, name: str, args: dict | None, capture: bool):
         self._rec = rec
-        self._name = name
-        self._args = args
+        self.name = name
+        self.args = args
+        self._ann = (_annotation_cls(CAPTURE_PREFIX + name, **(args or {}))
+                     if capture else None)
+        self.t0 = self.t1 = 0.0
 
     def __enter__(self) -> "_Span":
-        self._t0 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__enter__()
+            stack = getattr(_open, "stack", None)
+            if stack is None:
+                stack = _open.stack = []
+            stack.append(self)
+        self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc_info) -> None:
-        self._rec.complete(self._name, self._t0, time.perf_counter(),
-                           self._args)
+        self.t1 = time.perf_counter()
+        if self._ann is not None:
+            _open.stack.pop()
+            self._ann.__exit__(*exc_info)
+        if self._rec is not None:
+            self._rec.complete(self.name, self.t0, self.t1, self.args)
+
+    def set(self, **args) -> None:
+        """Add arguments known only once the work is done."""
+        if self._rec is not None:
+            self.args = {**(self.args or {}), **args}
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
 
 
 class _NullSpan:
-    """Shared no-op context for the disabled-tracer path."""
+    """Shared no-op context for the nothing-recording path."""
 
     __slots__ = ()
 
@@ -65,6 +121,9 @@ class _NullSpan:
         return self
 
     def __exit__(self, *exc_info) -> None:
+        pass
+
+    def set(self, **args) -> None:
         pass
 
 
@@ -122,9 +181,9 @@ class TraceRecorder:
 
     def complete(self, name: str, t0: float, t1: float,
                  args: dict | None = None) -> None:
-        """Record a finished span from its `time.perf_counter` endpoints
-        — the idiom every stats-timed site uses, so span durations equal
-        the wall seconds charged to the counters."""
+        """Record a finished span from its `time.perf_counter` endpoints:
+        the sink of every live span, and the way a span measured
+        elsewhere (a remote recorder's, a client's) is merged in."""
         buf = self._buf()
         ev = {"name": name, "cat": "racon_tpu", "ph": "X",
               "ts": self._us(t0), "dur": round(max(0.0, t1 - t0) * 1e6, 3),
@@ -141,9 +200,6 @@ class TraceRecorder:
         if args:
             ev["args"] = args
         buf.append(ev)
-
-    def span(self, name: str, **args) -> _Span:
-        return _Span(self, name, args or None)
 
     # ------------------------------------------------------------ emission
     def events(self) -> list[dict]:
@@ -223,8 +279,8 @@ class _TeeRecorder:
     process recorder (the serve layer's always-on flight ring,
     obs/flight.py): the job gets its own events AND the ring keeps
     recording, so a concurrent job's post-mortem dump has no blind
-    window. Only the recording surface (`complete`/`instant`/`span`)
-    fans out; `events`/`save` delegate to the primary recorder."""
+    window. Only the recording surface (`complete`/`instant`) fans
+    out; `events`/`save` delegate to the primary recorder."""
 
     def __init__(self, primary: TraceRecorder, *others: TraceRecorder):
         self._recs = (primary,) + others
@@ -237,9 +293,6 @@ class _TeeRecorder:
     def instant(self, name, args=None) -> None:
         for rec in self._recs:
             rec.instant(name, args)
-
-    def span(self, name: str, **args) -> _Span:
-        return _Span(self, name, args or None)
 
     def events(self) -> list[dict]:
         return self._recs[0].events()
@@ -335,11 +388,36 @@ def trace_matches(args: dict | None, trace_id: str) -> bool:
     return isinstance(tids, (list, tuple)) and any(_hit(t) for t in tids)
 
 
+def enabled() -> bool:
+    """Whether a span opened now records anywhere: a recorder is armed
+    or a profiler capture runs. Sites whose span arguments cost work to
+    build check it once per loop."""
+    return get_tracer() is not None or capturing()
+
+
 def span(name: str, **args):
-    """Convenience span: a real recording context when tracing is armed,
-    a shared no-op otherwise."""
+    """A live span when a recorder is armed or a capture runs, else the
+    shared null span."""
     tr = get_tracer()
-    return tr.span(name, **args) if tr is not None else _NULL_SPAN
+    capture = capturing()
+    if tr is None and not capture:
+        return _NULL_SPAN
+    return _Span(tr, name, args or None, capture)
+
+
+def timed(name: str, **args) -> _Span:
+    """As span(), but always a live span with `t0`/`t1`: for sites that
+    charge a counter or a histogram from the same endpoints."""
+    return _Span(get_tracer(), name, args or None, capturing())
+
+
+def tag(**args) -> None:
+    """Add arguments to this thread's innermost span open in a profiler
+    capture (none open: a no-op). How a measurement taken inside a span
+    — the compile seconds its dispatch paid — reaches the capture."""
+    stack = getattr(_open, "stack", None)
+    if stack:
+        stack[-1].set(**args)
 
 
 def instant(name: str, **args) -> None:

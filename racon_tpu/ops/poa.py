@@ -34,6 +34,7 @@ from __future__ import annotations
 import os
 
 from ..native import poa_batch
+from ..obs import trace
 from ..resilience import strict_mode
 from ..utils.logger import Logger, log_info, warn_dedup
 
@@ -269,7 +270,8 @@ class BatchPOA:
         possible on deep windows — see its module docstring)."""
         from .poa_graph import DeviceGraphPOA
 
-        packed = [_pack(w) for w in todo]
+        with trace.span("poa.pack", windows=len(todo)):
+            packed = [_pack(w) for w in todo]
         if self.engine == "fused":
             from .poa_fused import FusedPOA
 
@@ -341,11 +343,12 @@ class BatchPOA:
             engine.logger = self.logger
             results, statuses = engine.consensus(packed)
         leftover = []
-        for w, r in zip(todo, results):
-            if r is None:  # neither engine built it: host loop's turn
-                leftover.append(w)
-            else:
-                w.apply_trim(r[0], r[1], trim)
+        with trace.span("poa.apply", windows=len(todo)):
+            for w, r in zip(todo, results):
+                if r is None:  # neither engine built it: host loop's turn
+                    leftover.append(w)
+                else:
+                    w.apply_trim(r[0], r[1], trim)
         stats = getattr(engine, "last_stats", None) or {}
         if "committed" in stats:
             log_info(f"[racon_tpu::BatchPOA] device layer alignments: "

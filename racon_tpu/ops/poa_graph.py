@@ -591,12 +591,13 @@ class DeviceGraphPOA:
 
         # adaptive grid from the run's own job-shape histogram (no-op
         # when the scheduler is off — the static grid stays)
-        self.adapt(windows)
-        session = PoaSession(windows, self.match, self.mismatch, self.gap,
-                             self.max_nodes, self.max_pred, self.max_len,
-                             max_jobs=self.cycle_jobs,
-                             banded_only=self.banded_only,
-                             n_threads=self.num_threads)
+        with trace.span("session.start", windows=len(windows)):
+            self.adapt(windows)
+            session = PoaSession(windows, self.match, self.mismatch,
+                                 self.gap, self.max_nodes, self.max_pred,
+                                 self.max_len, max_jobs=self.cycle_jobs,
+                                 banded_only=self.banded_only,
+                                 n_threads=self.num_threads)
         bar = self.logger.bar if self.logger is not None else None
         total_layers = sum(max(0, len(w) - 1) for w in windows)
         if self.logger is not None and total_layers:
@@ -629,7 +630,9 @@ class DeviceGraphPOA:
             if freed >= threshold or not inflight:
                 burst = 0
                 while len(inflight) < depth:
-                    jobs = session.prepare(half)
+                    with trace.span("session.prepare") as sp:
+                        jobs = session.prepare(half)
+                        sp.set(jobs=jobs["n"] if jobs is not None else 0)
                     if jobs is None:
                         break
                     burst += jobs["n"]
@@ -661,7 +664,13 @@ class DeviceGraphPOA:
                      f"{self._env_stats} (envelope: nodes {self.max_nodes}, "
                      f"len {self.max_len}, pred {self.max_pred}, "
                      f"RING {RING})")
-        return session.finish(self.num_threads)
+        with trace.span("session.finish"):
+            out = session.finish(self.num_threads)
+        # free the window graphs here, inside a span, rather than
+        # whenever the session is collected (tens of ms on a 0.1 Mb job)
+        with trace.span("session.close"):
+            session.close()
+        return out
 
     #: bucket groups smaller than this merge upward into the next larger
     #: nonempty bucket: a slightly longer scan for a few jobs beats paying

@@ -210,7 +210,7 @@ class DispatchPipeline:
         """`label` names this loop in the trace (aligner / fused /
         host_poa); `describe(item) -> dict` supplies per-chunk span args
         (engine, bucket, job count). Both are ignored — zero cost — when
-        tracing is off."""
+        no span records (no recorder armed, no profiler capture)."""
         items = list(items)
         if self.device_latency_x > 0.0:
             # wrapped before instrumentation so the stall counts as
@@ -233,10 +233,11 @@ class DispatchPipeline:
         if self.faults is not None or self.watchdog is not None:
             pack, dispatch, wait, unpack = self._instrument(
                 pack, dispatch, wait, unpack)
-        tr = trace.get_tracer()
-        args_of = None
-        if tr is not None:
-            def args_of(idx, item):
+        def args_of(idx, item) -> dict:
+            return {}
+
+        if trace.enabled():
+            def args_of(idx, item) -> dict:
                 a = {"chunk": idx}
                 if label:
                     a["loop"] = label
@@ -245,10 +246,10 @@ class DispatchPipeline:
                 return a
         if self.depth == 0:
             self._run_sync(items, pack, dispatch, wait, unpack, on_error,
-                           tr, args_of)
+                           args_of)
             return
         self._run_async(items, pack, dispatch, wait, unpack, on_error,
-                        tr, args_of)
+                        args_of)
 
     def _instrument(self, pack, dispatch, wait, unpack):
         """Wrap the stage callbacks with the resilience hooks: fault
@@ -301,45 +302,33 @@ class DispatchPipeline:
         return pack_w, dispatch_w, wait_w, unpack_w
 
     def _run_sync(self, items, pack, dispatch, wait, unpack, on_error,
-                  tr=None, args_of=None):
-        # spans reuse the exact perf_counter endpoints the stats bumps
-        # charge, so per-stage span-duration sums equal the stage
-        # wall-clock counters by construction (tests/test_obs.py)
+                  args_of):
+        # each counter is charged from its span's own endpoints, so
+        # per-stage span-duration sums equal the stage wall-clock
+        # counters by construction (tests/test_obs.py)
         stats = self.stats
         for idx, item in enumerate(items):
+            a = args_of(idx, item)
             try:
-                t0 = time.perf_counter()
-                ops = pack(item)
-                t1 = time.perf_counter()
-                stats.bump("pack_s", t1 - t0)
-                if tr is not None:
-                    tr.complete("pipeline.pack", t0, t1, args_of(idx, item))
-                t0 = time.perf_counter()
-                handle = dispatch(item, ops)
-                t1 = time.perf_counter()
-                disp_dt = t1 - t0
+                with trace.timed("pipeline.pack", **a) as sp:
+                    ops = pack(item)
+                stats.bump("pack_s", sp.t1 - sp.t0)
+                with trace.timed("pipeline.device", seg="dispatch",
+                                 **a) as sp:
+                    handle = dispatch(item, ops)
+                disp_dt = sp.t1 - sp.t0
                 stats.bump("device_s", disp_dt)
                 stats.bump("chunks")
-                if tr is not None:
-                    tr.complete("pipeline.device", t0, t1,
-                                dict(args_of(idx, item), seg="dispatch"))
-                t0 = time.perf_counter()
-                res = wait(handle)
-                t1 = time.perf_counter()
-                stats.bump("device_s", t1 - t0)
+                # the wait ends once the result is on the host
+                with trace.timed("pipeline.device", seg="wait", **a) as sp:
+                    res = wait(handle)
+                stats.bump("device_s", sp.t1 - sp.t0)
                 if stats.hists is not None:
                     stats.hists.observe("pipeline.device",
-                                        disp_dt + (t1 - t0))
-                if tr is not None:
-                    tr.complete("pipeline.device", t0, t1,
-                                dict(args_of(idx, item), seg="wait"))
-                t0 = time.perf_counter()
-                unpack(item, res)
-                t1 = time.perf_counter()
-                stats.bump("unpack_s", t1 - t0)
-                if tr is not None:
-                    tr.complete("pipeline.unpack", t0, t1,
-                                args_of(idx, item))
+                                        disp_dt + (sp.t1 - sp.t0))
+                with trace.timed("pipeline.unpack", **a) as sp:
+                    unpack(item, res)
+                stats.bump("unpack_s", sp.t1 - sp.t0)
             except Exception as exc:
                 stats.bump("errors")
                 if on_error is None:
@@ -347,7 +336,7 @@ class DispatchPipeline:
                 on_error(item, exc)
 
     def _run_async(self, items, pack, dispatch, wait, unpack, on_error,
-                   tr=None, args_of=None):
+                   args_of):
         stats = self.stats
         fatal: list[BaseException] = []
         abort = threading.Event()
@@ -373,13 +362,10 @@ class DispatchPipeline:
                     if abort.is_set():
                         break
                     try:
-                        t0 = time.perf_counter()
-                        ops = pack(item)
-                        t1 = time.perf_counter()
-                        stats.bump("pack_s", t1 - t0)
-                        if tr is not None:
-                            tr.complete("pipeline.pack", t0, t1,
-                                        args_of(idx, item))
+                        with trace.timed("pipeline.pack",
+                                         **args_of(idx, item)) as sp:
+                            ops = pack(item)
+                        stats.bump("pack_s", sp.t1 - sp.t0)
                     except Exception as exc:
                         guard(item, exc)
                         continue
@@ -395,24 +381,19 @@ class DispatchPipeline:
                 if abort.is_set():
                     continue
                 idx, item, handle, disp_dt = entry
+                a = args_of(idx, item)
                 try:
-                    t0 = time.perf_counter()
-                    res = wait(handle)
-                    t1 = time.perf_counter()
-                    stats.bump("device_s", t1 - t0)
+                    # the wait ends once the result is on the host
+                    with trace.timed("pipeline.device", seg="wait",
+                                     **a) as sp:
+                        res = wait(handle)
+                    stats.bump("device_s", sp.t1 - sp.t0)
                     if stats.hists is not None:
                         stats.hists.observe("pipeline.device",
-                                            disp_dt + (t1 - t0))
-                    if tr is not None:
-                        tr.complete("pipeline.device", t0, t1,
-                                    dict(args_of(idx, item), seg="wait"))
-                    t0 = time.perf_counter()
-                    unpack(item, res)
-                    t1 = time.perf_counter()
-                    stats.bump("unpack_s", t1 - t0)
-                    if tr is not None:
-                        tr.complete("pipeline.unpack", t0, t1,
-                                    args_of(idx, item))
+                                            disp_dt + (sp.t1 - sp.t0))
+                    with trace.timed("pipeline.unpack", **a) as sp:
+                        unpack(item, res)
+                    stats.bump("unpack_s", sp.t1 - sp.t0)
                 except Exception as exc:
                     guard(item, exc)
 
@@ -441,19 +422,15 @@ class DispatchPipeline:
                     continue
                 idx, item, ops = entry
                 try:
-                    t0 = time.perf_counter()
-                    handle = dispatch(item, ops)
-                    t1 = time.perf_counter()
-                    stats.bump("device_s", t1 - t0)
+                    with trace.timed("pipeline.device", seg="dispatch",
+                                     **args_of(idx, item)) as sp:
+                        handle = dispatch(item, ops)
+                    stats.bump("device_s", sp.t1 - sp.t0)
                     stats.bump("chunks")
-                    if tr is not None:
-                        tr.complete("pipeline.device", t0, t1,
-                                    dict(args_of(idx, item),
-                                         seg="dispatch"))
                 except Exception as exc:
                     guard(item, exc)
                     continue
-                waiting_q.put((idx, item, handle, t1 - t0))
+                waiting_q.put((idx, item, handle, sp.t1 - sp.t0))
         except BaseException:
             # exceptional exit (KeyboardInterrupt is the real case): the
             # workers may be blocked on the bounded queues, so a plain
@@ -496,18 +473,16 @@ class DispatchPipeline:
             return fn(*args, **kwargs)
 
         def timed():
-            t0 = time.perf_counter()
+            sp = trace.timed("pipeline.fallback", job=idx)
             try:
-                if wd is None:
-                    return job()
-                return Watchdog(timeout=0.0, retries=wd.retries,
-                                backoff=wd.backoff).call(job, stats=stats)
+                with sp:
+                    if wd is None:
+                        return job()
+                    return Watchdog(timeout=0.0, retries=wd.retries,
+                                    backoff=wd.backoff).call(job,
+                                                             stats=stats)
             finally:
-                t1 = time.perf_counter()
-                stats.bump("fallback_s", t1 - t0)
-                tr = trace.get_tracer()
-                if tr is not None:
-                    tr.complete("pipeline.fallback", t0, t1, {"job": idx})
+                stats.bump("fallback_s", sp.t1 - sp.t0)
 
         if self.depth == 0:
             fut: Future = Future()

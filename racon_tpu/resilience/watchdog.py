@@ -91,15 +91,11 @@ class Watchdog:
                     stats.bump("retries")
                     stats.bump("backoff_s", delay)
                 if delay:
-                    t0 = time.perf_counter()
-                    time.sleep(delay)
                     from ..obs import trace
 
-                    tr = trace.get_tracer()
-                    if tr is not None:
-                        tr.complete("watchdog.backoff", t0,
-                                    time.perf_counter(),
-                                    {"attempt": attempt + 1})
+                    with trace.span("watchdog.backoff",
+                                    attempt=attempt + 1):
+                        time.sleep(delay)
 
     def _deadline(self, fn, stats, on_timeout):
         if self.timeout <= 0:

@@ -149,12 +149,15 @@ class OccupancyStats:
                 return False
             _seen_shapes.add(k)
         self.record_compile(engine, seconds)
-        # trace the compile as a span ending now (the charge is made
-        # right after the first dispatch returned, so now - seconds is
-        # the dispatch's start) — the Perfetto view of "where did the
-        # first chunk's stall go"
+        # the charge is made right after the first dispatch returned,
+        # so the compile is the span [now - seconds, now]: the Chrome
+        # recorder takes it as such (the Perfetto view of "where did
+        # the first chunk's stall go"); a profiler capture cannot take
+        # a span after the fact, so there the dispatch span open on
+        # this thread carries the seconds
         from ..obs import trace
 
+        trace.tag(compile_s=float(seconds))
         tr = trace.get_tracer()
         if tr is not None:
             import time

@@ -46,6 +46,7 @@ def run(sequences: str, overlaps: str, target_sequences: str,
     unsharded output byte-for-byte. Requires --split so there is more
     than one unit to scatter."""
     from .core.polisher import create_polisher, PolisherType
+    from .obs import jax_profile
 
     if not (0 <= shard_id < num_shards):
         raise RaconError(
@@ -84,17 +85,20 @@ def run(sequences: str, overlaps: str, target_sequences: str,
                   f"chunks [{lo}, {hi}) of {len(targets)}", file=sys.stderr)
             targets = targets[lo:hi]
 
-        for part in targets:
-            polisher = create_polisher(
-                sequences, overlaps, part,
-                PolisherType.kF if fragment_correction else PolisherType.kC,
-                window_length, quality_threshold, error_threshold, True,
-                match, mismatch, gap, threads, tpu_poa_batches,
-                tpu_banded_alignment, tpu_aligner_batches)
-            polisher.initialize()
-            for seq in polisher.polish(not include_unpolished):
-                out.write(b">" + seq.name.encode() + b"\n" + seq.data + b"\n")
-            out.flush()
+        with jax_profile():
+            for part in targets:
+                polisher = create_polisher(
+                    sequences, overlaps, part,
+                    PolisherType.kF if fragment_correction
+                    else PolisherType.kC,
+                    window_length, quality_threshold, error_threshold, True,
+                    match, mismatch, gap, threads, tpu_poa_batches,
+                    tpu_banded_alignment, tpu_aligner_batches)
+                polisher.initialize()
+                for seq in polisher.polish(not include_unpolished):
+                    out.write(b">" + seq.name.encode() + b"\n" + seq.data
+                              + b"\n")
+                out.flush()
     finally:
         shutil.rmtree(work, ignore_errors=True)
 

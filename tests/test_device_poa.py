@@ -215,6 +215,33 @@ def test_device_consensus_byte_identical_to_host():
         np.testing.assert_array_equal(dcov, hcov, err_msg=f"window {i}")
 
 
+def test_session_spans_nest_in_order(tmp_path):
+    """The session engine's spans, in the order it works: start, the
+    prepare/dispatch/commit rounds, finish, then the close that frees
+    the window graphs."""
+    from racon_tpu.obs import trace
+
+    rng = random.Random(6)
+    windows, _ = _make_windows(rng, 6, length=80, depth=5)
+    packed = [_pack(w) for w in windows]
+    eng = DeviceGraphPOA(3, -5, -4, num_threads=2, max_nodes=192,
+                         max_len=128, buckets=((96, 96), (192, 128)),
+                         batch_rows=8)
+    rec = trace.configure(str(tmp_path / "t.json"))
+    try:
+        eng.consensus(packed)
+    finally:
+        trace.reset()
+    names = [e["name"] for e in rec.events()
+             if e["ph"] == "X" and e["name"].startswith("session.")]
+    assert names[0] == "session.start"
+    assert names[-2:] == ["session.finish", "session.close"]
+    rounds = names[1:-2]
+    assert {"session.prepare", "session.dispatch",
+            "session.commit"} == set(rounds)
+    assert rounds.count("session.dispatch") == rounds.count("session.commit")
+
+
 def _block_swap_windows(rng):
     """Windows whose last layer is a homopolymer block swap: same length
     (so the 256-band is used) but the true path drifts ~300 columns off

@@ -9,6 +9,9 @@ Pins the PR-4 contracts:
     degradation counters exactly (both come from the same bump);
   - concurrent pipeline threads produce a parseable trace;
   - tracing off by default, and a traced run's FASTA is byte-identical;
+  - spans are live: inside a JAX profiler capture they are
+    `racon.<name>` annotations on the capture's clock, nested and in
+    order, and a run's `--tpu-jax-profile` is one capture;
   - the metrics registry namespaces (pipeline/sched/resilience/aligner),
     the --tpu-metrics dump, and the bench-facing snapshot;
   - leveled logging (quiet/info/debug), warn_dedup suppression, and the
@@ -396,7 +399,7 @@ def test_jax_profile_noop_and_safe(monkeypatch, tmp_path):
 
     # unset: a null context
     monkeypatch.delenv("RACON_TPU_PROFILE", raising=False)
-    with jax_profile("x"):
+    with jax_profile():
         pass
     # set but profiler broken: still a silent no-op, never a crash
     monkeypatch.setenv("RACON_TPU_PROFILE", str(tmp_path / "prof"))
@@ -406,5 +409,223 @@ def test_jax_profile_noop_and_safe(monkeypatch, tmp_path):
         raise RuntimeError("no profiler on this backend")
 
     monkeypatch.setattr(jax.profiler, "trace", broken)
-    with jax_profile("consensus"):
+    with jax_profile():
         pass
+
+
+# ------------------------------------------------ spans in a profiler capture
+def _capture_spans(directory):
+    """[(start_ns, end_ns, name without `racon.`, thread line, args)] of
+    the one capture under `directory`, in start order."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    files = glob.glob(os.path.join(str(directory), "**", "*.xplane.pb"),
+                      recursive=True)
+    assert len(files) == 1, files
+    out = []
+    for plane in ProfileData.from_file(files[0]).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for li, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name.startswith(trace.CAPTURE_PREFIX):
+                    out.append((e.start_ns, e.start_ns + e.duration_ns,
+                                e.name[len(trace.CAPTURE_PREFIX):], li,
+                                dict(e.stats)))
+    return sorted(out)
+
+
+def _one(spans, name):
+    got = [s for s in spans if s[2] == name]
+    assert len(got) == 1, (name, got)
+    return got[0]
+
+
+def test_span_is_shared_null_when_nothing_records():
+    assert not trace.capturing() and not trace.enabled()
+    assert trace.span("x", k=1) is trace.span("y")
+    with trace.span("x") as sp:
+        sp.set(n=1)  # a no-op, never an error
+    # timed() is live all the same: counters are charged from it
+    with trace.timed("x", k=1) as sp:
+        time.sleep(0.001)
+    assert sp.t1 - sp.t0 >= 0.001
+
+
+def test_live_span_records_its_own_endpoints(tmp_path):
+    rec = trace.configure(str(tmp_path / "t.json"))
+    with trace.span("outer", k=1) as sp:
+        sp.set(n=2)
+    ev, = [e for e in rec.events() if e["ph"] == "X"]
+    assert ev["name"] == "outer" and ev["args"] == {"k": 1, "n": 2}
+    assert ev["ts"] == pytest.approx((sp.t0 - rec._base) * 1e6, abs=0.01)
+    assert ev["dur"] == pytest.approx((sp.t1 - sp.t0) * 1e6, abs=0.01)
+
+
+def test_span_recorded_when_its_work_raises(tmp_path):
+    import jax
+
+    rec = trace.configure(str(tmp_path / "t.json"))
+    with jax.profiler.trace(str(tmp_path / "prof")):
+        with pytest.raises(ValueError):
+            with trace.span("fails"):
+                raise ValueError("boom")
+        # the failed span left this thread's capture stack: a later
+        # tag lands on nothing instead of on the dead span
+        trace.tag(compile_s=1.0)
+    assert [e["name"] for e in rec.events() if e["ph"] == "X"] == ["fails"]
+    assert "compile_s" not in (rec.events()[-1].get("args") or {})
+    assert _one(_capture_spans(tmp_path / "prof"), "fails")[4] == {}
+
+
+def test_profiler_capture_holds_polisher_spans_nested_and_in_order(
+        dataset, tmp_path):
+    """No recorder armed: the capture alone switches the spans on, and
+    the polisher's phases land in it nested and in order."""
+    import jax
+
+    assert trace.get_tracer() is None
+    with jax.profiler.trace(str(tmp_path / "prof")):
+        _polish(dataset, depth=2)
+    spans = _capture_spans(tmp_path / "prof")
+    init = _one(spans, "polisher.initialize")
+    parts = [_one(spans, f"polisher.{n}") for n in (
+        "load_targets", "load_reads", "load_overlaps", "align_overlaps",
+        "build_windows")]
+    for a, b in zip(parts, parts[1:]):
+        assert a[1] <= b[0], (a[2], b[2])
+    for p in parts:
+        assert init[0] <= p[0] and p[1] <= init[1] and p[3] == init[3]
+    align = parts[3]
+    bp = _one(spans, "polisher.breaking_points")
+    assert align[0] <= bp[0] and bp[1] <= align[1]
+    consensus = _one(spans, "polisher.consensus")
+    stitch = _one(spans, "polisher.stitch")
+    assert init[1] <= consensus[0] and consensus[1] <= stitch[0]
+    assert init[4]["windows"] > 0 and consensus[4]["engine"] == "host"
+    # the pipeline's chunk spans, on their worker threads, fall inside
+    # the consensus phase and carry their loop's arguments
+    chunks = [s for s in spans if s[2].startswith("pipeline.")]
+    assert {"pipeline.pack", "pipeline.device",
+            "pipeline.unpack"} <= {s[2] for s in chunks}
+    for s in chunks:
+        assert consensus[0] <= s[0] and s[1] <= consensus[1]
+        assert s[4]["loop"] == "host_poa"
+
+
+def test_capture_and_recorder_see_the_same_spans(dataset, tmp_path):
+    import jax
+
+    rec = trace.configure(str(tmp_path / "t.json"))
+    with jax.profiler.trace(str(tmp_path / "prof")):
+        _polish(dataset, depth=2)
+    chrome = sorted(e["name"] for e in rec.events() if e["ph"] == "X")
+    captured = sorted(s[2] for s in _capture_spans(tmp_path / "prof"))
+    assert chrome == captured and "polisher.stitch" in chrome
+
+
+def test_span_sums_match_stage_stats_under_capture(dataset, tmp_path):
+    """The counters and both sinks share each span's endpoints."""
+    import jax
+
+    with jax.profiler.trace(str(tmp_path / "prof")):
+        _, polisher = _polish(dataset, depth=2)
+    stats = polisher.stage_stats
+    sums = {}
+    for s0, s1, name, _, _ in _capture_spans(tmp_path / "prof"):
+        if name.startswith("pipeline."):
+            stage = name.split(".", 1)[1]
+            sums[stage] = sums.get(stage, 0.0) + (s1 - s0) / 1e9
+    for stage, key in (("pack", "pack_s"), ("device", "device_s"),
+                       ("unpack", "unpack_s")):
+        assert sums.get(stage, 0.0) == pytest.approx(
+            stats[key], rel=0.05, abs=1e-3), stage
+
+
+def test_compile_seconds_ride_the_open_dispatch_span(tmp_path):
+    """A compile is charged after its dispatch returned: the Chrome
+    recorder takes it as its own `xla.compile` span, a capture as an
+    argument of the dispatch span still open."""
+    import jax
+
+    from racon_tpu.sched.telemetry import OccupancyStats
+
+    rec = trace.configure(str(tmp_path / "t.json"))
+    key = ("test_obs", time.perf_counter())
+    with jax.profiler.trace(str(tmp_path / "prof")):
+        with trace.span("pipeline.device", seg="dispatch"):
+            assert OccupancyStats().record_compile_once("aligner", key,
+                                                        1.25)
+    disp = _one(_capture_spans(tmp_path / "prof"), "pipeline.device")
+    assert disp[4] == {"seg": "dispatch", "compile_s": 1.25}
+    names = [e["name"] for e in rec.events() if e["ph"] == "X"]
+    assert sorted(names) == ["pipeline.device", "xla.compile"]
+
+
+def test_concurrent_pipeline_spans_under_capture(tmp_path):
+    """Five writer threads, each with its own capture stack: every
+    stage span lands once, on the thread that ran it."""
+    import jax
+
+    from racon_tpu.pipeline import DispatchPipeline
+
+    with jax.profiler.trace(str(tmp_path / "prof")):
+        with DispatchPipeline(depth=2, fallback_workers=3) as pl:
+            for _ in range(20):
+                pl.submit_fallback(lambda: time.sleep(0.0005))
+            pl.run(range(30), pack=lambda i: i, dispatch=lambda i, o: o,
+                   wait=lambda h: h, unpack=lambda i, r: None,
+                   label="t", describe=lambda i: {"i": i})
+            pl.drain_fallback()
+    counts, lines = {}, {}
+    for _, _, name, line, args in _capture_spans(tmp_path / "prof"):
+        counts[name] = counts.get(name, 0) + 1
+        lines.setdefault(name, set()).add(line)
+    assert counts == {"pipeline.pack": 30, "pipeline.device": 60,
+                      "pipeline.unpack": 30, "pipeline.fallback": 20}
+    assert len(lines["pipeline.pack"]) == 1
+    assert lines["pipeline.pack"].isdisjoint(lines["pipeline.unpack"])
+
+
+def test_aligner_chunk_spans_name_their_shape(tmp_path):
+    from racon_tpu.ops.align import BatchAligner
+    from racon_tpu.pipeline import DispatchPipeline
+
+    rng = random.Random(5)
+    pairs = []
+    for n in (300, 320, 700):
+        t = bytes(rng.choice(ACGT) for _ in range(n))
+        pairs.append((_mutate(rng, t, 0.05), t))
+    rec = trace.configure(str(tmp_path / "t.json"))
+    with DispatchPipeline(depth=2) as pl:
+        BatchAligner(band_width=64).align(pairs, pipeline=pl)
+    events = [e for e in rec.events() if e["ph"] == "X"]
+    plan = [e for e in events if e["name"] == "aligner.plan"]
+    assert len(plan) == 1 and plan[0]["args"] == {"pairs": 3, "chunks": 2}
+    chunk = [e["args"] for e in events if e["name"] == "pipeline.pack"]
+    assert sorted((a["edge"], a["jobs"]) for a in chunk) == [(512, 2),
+                                                            (1024, 1)]
+    for a in chunk:
+        assert a["band"] == 64 and a["kernel"] in ("xla", "pallas")
+        assert a["lanes"] >= a["jobs"] and a["lane_cap"] >= a["lanes"]
+
+
+def test_jax_profile_one_capture_per_run(dataset, tmp_path, monkeypatch,
+                                         capsys):
+    """`--tpu-jax-profile` is one capture of the whole run: parsing,
+    consensus and the stitch side by side, no per-phase directories."""
+    from racon_tpu import cli
+
+    prof = tmp_path / "prof"
+    reads, paf, draft = dataset
+    assert cli.main(["--tpu-jax-profile", str(prof), "-t", "2",
+                     reads, paf, draft]) == 0
+    assert capsys.readouterr().out.startswith(">")
+    assert not (prof / "align").exists()
+    assert not (prof / "consensus").exists()
+    names = {s[2] for s in _capture_spans(prof)}
+    assert {"polisher.load_targets", "polisher.consensus",
+            "polisher.stitch"} <= names
+    assert "RACON_TPU_PROFILE" not in os.environ  # restored after main
