@@ -115,8 +115,12 @@ def _banded_nw_kernel(q, t, q_len, t_len, offsets, band: int, n_waves: int,
     if packed:
         from .encode import unpack_2bit_jax
 
-        q = unpack_2bit_jax(q, q.shape[1] * 4, q_len)
-        t = unpack_2bit_jax(t, t.shape[1] * 4, t_len)
+        # unpack once per program: without the barrier XLA sinks the
+        # shifts into the wavefront scan, whose state then carries the
+        # packed operands and re-unpacks both of them every wavefront
+        q, t = jax.lax.optimization_barrier(
+            (unpack_2bit_jax(q, q.shape[1] * 4, q_len),
+             unpack_2bit_jax(t, t.shape[1] * 4, t_len)))
 
     batch = q.shape[0]
     ks = jnp.arange(band, dtype=jnp.int32)
@@ -487,8 +491,8 @@ class BatchAligner:
 
         from . import align_pallas
         from .dtypes import aligner_int16_ok, kernel_plan
-        from .encode import (encode_padded, pack_2bit, pack_bases_enabled,
-                             packable)
+        from .encode import (encode, encode_padded, pack_2bit,
+                             pack_bases_enabled)
         from ..parallel.mesh import BatchRunner
         from ..pipeline import DispatchPipeline
         from ..resilience import strict_mode
@@ -530,6 +534,15 @@ class BatchAligner:
                     "pallas" if use else "xla", dtype)
             return plan
 
+        def packs(idx) -> bool:
+            """2-bit base packing: ACGT-only chunks ship a quarter of the
+            sequence bytes and unpack on device (byte-identical; any N
+            in the chunk keeps the int8 operands, and padding lanes are
+            ACGT). From the pairs alone, so the span args, taken before
+            pack() runs, can say it too."""
+            return pack_bases_enabled() and all(
+                bool((encode(s) < 4).all()) for i in idx for s in pairs[i])
+
         def pack(chunk):
             edge, band, n_waves, idx = chunk
             kern, dtype = plan_for(edge, band)
@@ -547,11 +560,7 @@ class BatchAligner:
                                           edge)
             offs = np.stack([band_offsets(int(ql), int(tl), band, n_waves)
                              for ql, tl in zip(q_lens, t_lens)])
-            # 2-bit base packing: ACGT-only chunks ship a quarter of the
-            # sequence bytes and unpack on device (byte-identical; any N
-            # in the chunk keeps the int8 operands)
-            do_pack = (pack_bases_enabled() and packable(q_arr, q_lens)
-                       and packable(t_arr, t_lens))
+            do_pack = packs(idx)
             if kern == "pallas":
                 q_op, t_op = align_pallas.build_ext(q_arr, t_arr, band)
                 if do_pack:
@@ -692,7 +701,8 @@ class BatchAligner:
                         len(idx)),
                     "lane_cap": self._lane_cap(n_waves, band,
                                                runner.n_devices),
-                    "kernel": plan_for(edge, band)[0]}
+                    "kernel": plan_for(edge, band)[0],
+                    "packed": packs(idx)}
 
         pl.run(chunks, pack, dispatch, wait, unpack,
                on_error=(chunk_error if on_reject is not None

@@ -147,3 +147,36 @@ def test_device_aligner_through_polisher(reference_data):
     # banded device CIGARs may shift a few window boundaries (the reference
     # accepts the same CPU-vs-GPU divergence); structure must agree broadly
     assert n_equal >= int(0.9 * len(host.windows))
+
+
+@pytest.mark.parametrize("edge", [512, 1024])
+def test_packed_kernel_matches_int8_kernel(edge):
+    """The 2-bit packed program returns the int8 program's bytes: random
+    ACGT pairs with lengths off a multiple of 4, at the bucket edge and
+    skewed against each other."""
+    from racon_tpu.ops.align import _kernel_for
+    from racon_tpu.ops.encode import encode_padded, pack_2bit, packable
+
+    rng = np.random.default_rng(edge)
+    band, n_waves = 128, 2 * edge + 1
+    lengths = [(edge - 3, edge - 2), (edge, edge), (edge, edge - 1),
+               (edge // 2 + 1, edge - 5), (edge - 7, edge * 3 // 4 + 2),
+               (97, 131), (edge - 1, edge // 3 + 3), (5, 9)]
+    pairs = []
+    for lq, lt in lengths:
+        t = _random_seq(rng, lt)
+        q = _mutate(rng, t, sub=0.08, ins=0.04, dele=0.04)[:lq]
+        pairs.append((q + _random_seq(rng, lq - len(q)), t))
+    q_arr, q_lens = encode_padded([p[0] for p in pairs], edge)
+    t_arr, t_lens = encode_padded([p[1] for p in pairs], edge)
+    assert [tuple(x) for x in zip(q_lens, t_lens)] == lengths
+    assert packable(q_arr, q_lens) and packable(t_arr, t_lens)
+    offs = np.stack([band_offsets(int(ql), int(tl), band, n_waves)
+                     for ql, tl in zip(q_lens, t_lens)])
+    lens = (q_lens.astype(np.int32), t_lens.astype(np.int32), offs)
+    ops, meta = _kernel_for(band, n_waves, "int32", False)(q_arr, t_arr,
+                                                           *lens)
+    ops_p, meta_p = _kernel_for(band, n_waves, "int32", True)(
+        pack_2bit(q_arr), pack_2bit(t_arr), *lens)
+    np.testing.assert_array_equal(np.asarray(ops_p), np.asarray(ops))
+    np.testing.assert_array_equal(np.asarray(meta_p), np.asarray(meta))

@@ -16,6 +16,8 @@ driver's workers all import every test file.
 
 from __future__ import annotations
 
+import re
+
 import numpy as np
 import pytest
 
@@ -45,7 +47,14 @@ def one_chip():
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
+    # the programs compile as the main path runs them, with 64-bit types
+    # off: a fused POA build earlier in this process turns them on for
+    # good (ops/poa_fused.py), and the Pallas kernels' lowering then
+    # recurses without end
+    prev_x64 = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", False)
     yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_x64", prev_x64)
     jax.config.update("jax_enable_compilation_cache", prev)
     compilation_cache.reset_cache()
 
@@ -115,18 +124,55 @@ def test_pallas_wavefront_largest_bucket_compiles(one_chip, score_dtype):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_xla_aligner_edge_compiles_for_v5e(one_chip):
+def _loop_cycles(hlo: str) -> dict[str, int]:
+    """Each `while` of an optimized program: its tuple type, mapped to
+    the compiler's `estimated_cycles` summed over its body."""
+    bodies, name = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"(?:ENTRY )?(%[\w.\-]+) ", line)
+        if m and line.rstrip().endswith("{"):
+            name = m.group(1)
+            bodies[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name:
+            bodies[name].append(line)
+    return {line.split(" while(")[0]: sum(
+                int(c) for c in re.findall(r'"estimated_cycles":"(\d+)"',
+                                           "\n".join(bodies[body])))
+            for lines in bodies.values() for line in lines
+            if " while(" in line
+            for body in re.findall(r"body=(%[\w.\-]+)", line)}
+
+
+@pytest.mark.parametrize("edge, band", [(8192, 896), (32768, 2048)])
+def test_xla_aligner_edge_compiles_for_v5e(one_chip, edge, band):
+    """The bucket ~8 kb ONT overlaps land in, and the longest reads' one,
+    at the batch width the backpointer budget gives. The 2-bit operands
+    are unpacked once, before the wavefront scan: no loop carries them
+    packed, and the forward loop costs what the int8 program's does."""
     from racon_tpu.ops.align import BatchAligner, _kernel_for
 
-    # the bucket ~8 kb ONT overlaps land in, at its auto band and the
-    # batch width its backpointer budget gives
-    edge, band = 8192, 896
     n_waves = 2 * edge + 1
-    lanes = BatchAligner.MAX_BP_BYTES // (n_waves * band // 4)
-    fn = _kernel_for.__wrapped__(band, n_waves, "int32", True)
-    _compile(fn, one_chip, ((lanes, edge // 4), jnp.uint8),
-             ((lanes, edge // 4), jnp.uint8), ((lanes,), jnp.int32),
-             ((lanes,), jnp.int32), ((lanes, n_waves), jnp.int32))
+    lanes = BatchAligner()._lane_cap(n_waves, band, 1)
+    rest = (((lanes,), jnp.int32), ((lanes,), jnp.int32),
+            ((lanes, n_waves), jnp.int32))
+    loops = {}
+    for packed, width, dtype in ((True, edge // 4, jnp.uint8),
+                                 (False, edge, jnp.int8)):
+        fn = _kernel_for.__wrapped__(band, n_waves, "int32", packed)
+        compiled = _compile(fn, one_chip, ((lanes, width), dtype),
+                            ((lanes, width), dtype), *rest)
+        loops[packed] = _loop_cycles(compiled.as_text())
+    packed_operand = f"u8[{lanes},{edge // 4}]"
+    assert loops[True] and not any(packed_operand in carry
+                                   for carry in loops[True])
+    # the forward scan is the costlier of the two loops in both programs
+    fwd_packed = max(loops[True].values())
+    fwd_int8 = max(loops[False].values())
+    assert fwd_int8 > 0
+    assert abs(fwd_packed - fwd_int8) <= 0.1 * fwd_int8, (fwd_packed,
+                                                          fwd_int8)
 
 
 def test_fused_depth_bucket_compiles_for_v5e(one_chip, tpu_branch):

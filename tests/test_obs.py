@@ -612,6 +612,32 @@ def test_aligner_chunk_spans_name_their_shape(tmp_path):
         assert a["lanes"] >= a["jobs"] and a["lane_cap"] >= a["lanes"]
 
 
+
+@pytest.mark.parametrize("pack_bases", ["1", "0"])
+def test_aligner_chunk_spans_say_whether_packed(tmp_path, monkeypatch,
+                                                pack_bases):
+    """`packed` on a chunk's spans: ACGT-only chunks ship 2-bit operands,
+    a chunk with an N keeps int8 ones, and the knob turns packing off."""
+    from racon_tpu.ops.align import BatchAligner
+    from racon_tpu.pipeline import DispatchPipeline
+
+    monkeypatch.setenv("RACON_TPU_PACK_BASES", pack_bases)
+    rng = random.Random(6)
+    pairs = []
+    for n in (300, 700):
+        t = bytes(rng.choice(ACGT) for _ in range(n))
+        pairs.append((_mutate(rng, t, 0.05), t))
+    q, t = pairs[1]
+    pairs[1] = (q[:50] + b"N" + q[51:], t)
+    rec = trace.configure(str(tmp_path / "t.json"))
+    with DispatchPipeline(depth=2) as pl:
+        BatchAligner(band_width=64).align(pairs, pipeline=pl)
+    spans = [e["args"] for e in rec.events() if e["ph"] == "X"
+             and e["name"] in ("pipeline.pack", "pipeline.device")]
+    assert len(spans) == 6
+    assert {(a["edge"], a["packed"]) for a in spans} == {
+        (512, pack_bases == "1"), (1024, False)}
+
 def test_jax_profile_one_capture_per_run(dataset, tmp_path, monkeypatch,
                                          capsys):
     """`--tpu-jax-profile` is one capture of the whole run: parsing,
