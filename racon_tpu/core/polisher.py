@@ -16,6 +16,7 @@ wherever JAX is pointed (TPU chip(s) or CPU), optionally sharded over a mesh
 
 from __future__ import annotations
 
+import collections
 import enum
 import os
 import time
@@ -541,10 +542,11 @@ class Polisher:
             log.log("[racon_tpu::Polisher.initialize] loaded sequences")
             log.log()
 
-        with trace.span("polisher.load_overlaps"):
+        with trace.span("polisher.load_overlaps") as sp:
             # -- overlaps streamed; per-query filtering (polisher.cpp:284-355)
-            overlaps = self._load_overlaps(name_to_id, id_to_id,
-                                           has_data, has_reverse_data)
+            overlaps, counts = self._load_overlaps(
+                name_to_id, id_to_id, has_data, has_reverse_data)
+            sp.set(kept=len(overlaps), **counts)
             if not overlaps and self.target_range is None:
                 # a fragment read-range shard may legitimately hold only
                 # targets without overlaps (they come back unpolished, and
@@ -565,7 +567,7 @@ class Polisher:
 
         log.log()
 
-        with trace.span("polisher.build_windows"):
+        with trace.span("polisher.build_windows") as sp:
             # -- windows (polisher.cpp:384-399); in range mode only the grid
             #    positions with lo <= start < hi materialize, but `rank`
             #    stays the GLOBAL grid rank so per-window identity (and
@@ -595,6 +597,7 @@ class Polisher:
 
             # -- layer assignment (polisher.cpp:403-457)
             wl = self.window_length
+            layers = 0
             for o in overlaps:
                 self.targets_coverages[o.t_id] += 1
                 seq = self.sequences[o.q_id]
@@ -631,7 +634,10 @@ class Polisher:
                     self.windows[window_id].add_layer(
                         data, qual, int(t_first - window_start),
                         int(t_last1 - window_start - 1))
+                    layers += 1
                 o.breaking_points = None
+            sp.set(targets=targets_size, windows=len(self.windows),
+                   layers=layers)
 
             log.log("[racon_tpu::Polisher.initialize] transformed data "
                     "into windows")
@@ -640,7 +646,10 @@ class Polisher:
         self.emit_progress(0, len(self.windows), phase="consensus")
 
     def _load_overlaps(self, name_to_id, id_to_id, has_data, has_reverse_data):
+        """The overlaps kept by the per-query filter, and the counts of
+        rows parsed and of rows dropped for error and for self-overlap."""
         overlaps: list = []
+        counts = {"rows": 0, "dropped_error": 0, "dropped_self": 0}
         error_threshold = self.error_threshold
         is_kc = self.type == PolisherType.kC
 
@@ -657,6 +666,8 @@ class Polisher:
                 if o is None:
                     continue
                 if o.error > error_threshold or o.q_id == o.t_id:
+                    counts["dropped_error" if o.error > error_threshold
+                           else "dropped_self"] += 1
                     arr[i] = None
                     continue
                 if is_kc:
@@ -676,6 +687,7 @@ class Polisher:
         while more:
             chunk: list = []
             more = self.oparser.parse(chunk, KCHUNK_SIZE)
+            counts["rows"] += len(chunk)
             for o in chunk:
                 o.transmute(self.sequences, name_to_id, id_to_id)
                 if not o.is_valid:
@@ -695,7 +707,7 @@ class Polisher:
                 has_reverse_data[f.q_id] = True
             else:
                 has_data[f.q_id] = True
-        return overlaps
+        return overlaps, counts
 
     # ------------------------------------------------------- alignment phase
     def find_overlap_breaking_points(self, overlaps: list) -> None:
@@ -746,12 +758,18 @@ class Polisher:
                               else max(1, self.num_threads
                                        // pipeline.fallback_workers))
 
-                def on_reject(idxs):
+                #: pair index -> why the aligner left it to the host; one
+                #: dict.update per call, so the pipeline's threads may
+                #: report at once
+                host_reasons: dict[int, str] = {}
+
+                def on_reject(idxs, reason):
                     # rejected pairs (too long for any bucket, or band-
                     # clipped) start host-aligning the moment they are
                     # known — the reference's GPU->CPU fallback
                     # (cudapolisher.cpp:203-213), overlapped with the
                     # device pass instead of serialized after it
+                    host_reasons.update(dict.fromkeys(idxs, reason))
                     fb.extend(pipeline.map_fallback(
                         idxs,
                         lambda sub: nw_cigar_batch(
@@ -773,6 +791,8 @@ class Polisher:
                              f"back to host aligner ({cancelled} fallback "
                              f"jobs cancelled, {drained} drained)")
                     self.logger.bar_total(len(pairs))  # restart progress
+                    host_reasons.update(dict.fromkeys(range(len(pairs)),
+                                                      "device_failure"))
                     return [None] * len(pairs), set()
 
                 try:
@@ -799,6 +819,12 @@ class Polisher:
                         exc, "Polisher.initialize"))
                 finally:
                     pipeline.close()
+                # every pair asked of the device, and why those that left
+                # it did: the benchmark's guard against a cell timing the
+                # host aligner unseen
+                self.scheduler.stats.record_pairs(
+                    "aligner", len(pairs),
+                    collections.Counter(host_reasons.values()))
 
             # host exact aligner for everything the device didn't take and
             # the fallback pool didn't already finish
@@ -905,8 +931,9 @@ class Polisher:
         else:
             self._consensus_pass()
             with trace.timed("polisher.stitch") as sp:
-                dst = self._stitch(drop_unpolished_sequences)
-                sp.set(sequences=len(dst))
+                dst, targets = self._stitch(drop_unpolished_sequences)
+                sp.set(sequences=len(dst), targets=targets,
+                       dropped=targets - len(dst))
             stitch_s = sp.t1 - sp.t0
         self.emit_progress(len(self.windows), len(self.windows),
                            phase="stitch", sequences=len(dst))
@@ -1040,16 +1067,19 @@ class Polisher:
         return create_sequence(self.sequences[last.id].name + tags,
                                bytes(polished_data))
 
-    def _stitch(self, drop_unpolished_sequences: bool) -> list[Sequence]:
+    def _stitch(self, drop_unpolished_sequences: bool
+                ) -> tuple[list[Sequence], int]:
         """Stitch per-window consensus back into whole sequences, one
-        contig at a time."""
+        contig at a time; returns them and the number of targets
+        stitched (the dropped ones included)."""
         dst: list[Sequence] = []
-        for start, end in self._contig_slices():
+        slices = self._contig_slices()
+        for start, end in slices:
             seq = self._stitch_contig(self.windows[start:end],
                                       drop_unpolished_sequences)
             if seq is not None:
                 dst.append(seq)
-        return dst
+        return dst, len(slices)
 
     def emit_observability(self) -> None:
         """End-of-run observability emission — every part a no-op when

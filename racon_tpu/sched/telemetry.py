@@ -65,12 +65,16 @@ class OccupancyStats:
     Per engine:
       compiles      distinct program shapes built this process
       compile_s     wall seconds spent in those shapes' first dispatch
+      pairs         (aligner) overlaps the polisher asked it to align
+      host_pairs    (aligner) of those, the ones aligned off the device,
+                    split by reason in host_pairs_by_reason
     """
 
     def __init__(self):
         self._lock = threading.Lock()
         self._buckets: dict[tuple[str, str], dict] = {}
         self._compiles: dict[str, dict] = {}
+        self._pairs: dict[str, dict] = {}
         #: optional obs.hist.HistogramSet: per-engine compile wall time
         #: observed as a latency distribution (`compile.<engine>`) —
         #: the "how long does a new shape stall a round" view the serve
@@ -124,6 +128,23 @@ class OccupancyStats:
             if full_mesh_cells is not None:
                 b["full_mesh_cells"] = (b.get("full_mesh_cells", 0)
                                         + int(full_mesh_cells))
+
+    def record_pairs(self, engine: str, pairs: int,
+                     host: dict[str, int]) -> None:
+        """Account one alignment pass: `pairs` the engine was asked to
+        align, and `host` ({reason: count}) of them aligned off the
+        device instead."""
+        with self._lock:
+            self._add_pairs(engine, pairs, host)
+
+    def _add_pairs(self, engine: str, pairs: int, host: dict) -> None:
+        c = self._pairs.setdefault(engine, {
+            "pairs": 0, "host_pairs": 0, "host_pairs_by_reason": {}})
+        c["pairs"] += int(pairs)
+        by = c["host_pairs_by_reason"]
+        for reason, n in host.items():
+            c["host_pairs"] += int(n)
+            by[reason] = by.get(reason, 0) + int(n)
 
     def record_compile(self, engine: str, seconds: float,
                        count: int = 1) -> None:
@@ -179,7 +200,11 @@ class OccupancyStats:
             buckets = {k: _copy_bucket(v)
                        for k, v in other._buckets.items()}
             compiles = {k: dict(v) for k, v in other._compiles.items()}
+            pairs = {k: (v["pairs"], dict(v["host_pairs_by_reason"]))
+                     for k, v in other._pairs.items()}
         with self._lock:
+            for engine, (n, host) in pairs.items():
+                self._add_pairs(engine, n, host)
             for key, b in buckets.items():
                 mine = self._buckets.get(key)
                 if mine is None:
@@ -200,12 +225,15 @@ class OccupancyStats:
 
     def snapshot(self) -> dict:
         """{engine: {"buckets": {bucket: {..., "occupancy_pct"}},
-                     "occupancy_pct", "compiles", "compile_s"}} —
+                     "occupancy_pct", "compiles", "compile_s",
+                     "pairs", "host_pairs", "host_pairs_by_reason"}} —
         JSON-ready; empty dict when nothing was dispatched."""
         with self._lock:
             buckets = {k: _copy_bucket(v)
                        for k, v in self._buckets.items()}
             compiles = {k: dict(v) for k, v in self._compiles.items()}
+            pairs = {k: dict(v, host_pairs_by_reason=dict(
+                v["host_pairs_by_reason"])) for k, v in self._pairs.items()}
         out: dict = {}
         for (engine, bucket), b in sorted(buckets.items()):
             e = out.setdefault(engine, {"buckets": {}})
@@ -252,6 +280,8 @@ class OccupancyStats:
             e = out.setdefault(engine, {"buckets": {}})
             e["compiles"] = c["compiles"]
             e["compile_s"] = round(c["compile_s"], 3)
+        for engine, c in pairs.items():
+            out.setdefault(engine, {"buckets": {}}).update(c)
         return out
 
     def summary(self) -> str | None:

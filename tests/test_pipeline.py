@@ -366,8 +366,10 @@ def test_aligner_depth0_vs_depth2_with_reject_fallback():
     for depth in (0, 2):
         al = BatchAligner(band_width=64, max_length=512)
         fb = []
+        reasons = {}
         with DispatchPipeline(depth=depth) as pl:
-            def on_reject(idxs, pl=pl, fb=fb):
+            def on_reject(idxs, reason, pl=pl, fb=fb, reasons=reasons):
+                reasons.update(dict.fromkeys(idxs, reason))
                 fb.extend(pl.map_fallback(
                     idxs, lambda sub: nw_cigar_batch(
                         [pairs[i] for i in sub], n_threads=2)))
@@ -376,6 +378,10 @@ def test_aligner_depth0_vs_depth2_with_reject_fallback():
             pl.drain_fallback()
         rejected = sorted(i for sub, _ in fb for i in sub)
         assert long_idx in rejected
+        # each rejected pair is reported once, under why it left the device
+        assert sorted(reasons) == rejected
+        assert reasons[long_idx] == "ladder"
+        assert set(reasons.values()) <= {"ladder", "band", "cost"}
         cigars = {}
         for sub, fut in fb:
             for i, c in zip(sub, fut.result()):

@@ -260,7 +260,7 @@ def test_aligner_circuit_breaker_trips(monkeypatch):
     al = BatchAligner(band_width=64)
     with DispatchPipeline(depth=0) as pl:
         with pytest.raises(DeviceError, match="consecutive"):
-            al.align(pairs, pipeline=pl, on_reject=rejected.extend)
+            al.align(pairs, pipeline=pl, on_reject=lambda idxs, reason: rejected.extend(idxs))
         assert pl.stats.snapshot()["breaker_trips"] == 1
 
 

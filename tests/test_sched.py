@@ -314,7 +314,7 @@ def test_repacked_chunk_fault_still_falls_back(monkeypatch, capsys):
         al = BatchAligner(band_width=64, scheduler=sched)
         fb = []
         with DispatchPipeline(depth=2) as pl:
-            def on_reject(idxs, pl=pl, fb=fb):
+            def on_reject(idxs, reason, pl=pl, fb=fb):
                 fb.extend(pl.map_fallback(
                     idxs, lambda sub: nw_cigar_batch(
                         [pairs[i] for i in sub], n_threads=2)))

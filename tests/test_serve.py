@@ -466,9 +466,9 @@ def test_batcher_mixed_params_do_not_merge(dataset):
     assert batcher.counters["iterations"] == 2
     assert batcher.counters["max_jobs_in_iteration"] == 1
     out_a = b"".join(b">" + s.name.encode() + b"\n" + s.data + b"\n"
-                     for s in pa._stitch(True))
+                     for s in pa._stitch(True)[0])
     out_b = b"".join(b">" + s.name.encode() + b"\n" + s.data + b"\n"
-                     for s in pb._stitch(True))
+                     for s in pb._stitch(True)[0])
     assert out_a == polish_solo(dataset)
     assert out_b == polish_solo(dataset, match=5)
     assert out_a != out_b  # the scores genuinely differ on this input
@@ -489,7 +489,7 @@ def test_batcher_persistent_engine_cache_and_host_overhead(dataset):
         p.initialize()
         batcher.consensus(p)
         return b"".join(b">" + s.name.encode() + b"\n" + s.data + b"\n"
-                        for s in p._stitch(True))
+                        for s in p._stitch(True)[0])
 
     out1 = run_job()
     out2 = run_job()
