@@ -11,7 +11,8 @@ batched fixed-shape XLA program:
 
   - the host densifies each window's current graph into topo-ordered arrays
     (node codes, predecessor rank lists, band centers, sink flags);
-  - the device kernel scans nodes in topological order (`lax.scan`), each
+  - the device kernel scans nodes in topological order, up to the batch's
+    largest graph (a `lax.fori_loop` whose trip count the batch sets), each
     step computing one DP row for the whole batch: gather at most P
     predecessor rows, diagonal/vertical maxima, then the in-row gap
     recurrence as a running max (`lax.cummax`) — a formulation with no
@@ -186,6 +187,31 @@ def max_pred_distance(preds: np.ndarray) -> int:
     return int(np.where(preds > 0, k1 - preds, 0).max(initial=0))
 
 
+#: node steps per iteration of the XLA program's sweep on TPU; the sweep
+#: length is rounded up to it
+SWEEP_UNROLL = 4
+
+
+def live_nodes(codes):
+    """A batch's largest live graph in nodes: one past the last node that
+    is not pad (code 5) in any row of densified codes [B, N] — the
+    session pads each job's codes with 5 past its node count. Takes a
+    host array or a traced one: the dispatcher's counters and the
+    program read the same formula."""
+    live = (codes != 5).any(axis=0)
+    return (live * np.arange(1, codes.shape[-1] + 1, dtype=np.int32)).max()
+
+
+def swept_nodes(n_live, n_nodes: int):
+    """DP rows the XLA program sweeps for a batch whose largest graph has
+    `n_live` nodes in a bucket of `n_nodes`: `n_live` rounded up to
+    SWEEP_UNROLL (a bucket that is not a multiple of it steps singly, so
+    the sweep never passes the bucket). Host ints and traced values
+    alike."""
+    u = SWEEP_UNROLL if n_nodes % SWEEP_UNROLL == 0 else 1
+    return (n_live + u - 1) // u * u
+
+
 def _mark_compiled(eng, nb: int, lb: int, ring_ok: bool, seconds: float,
                    kernel: str = "xla", dtype: str = "int32",
                    packed: bool = False) -> None:
@@ -341,15 +367,33 @@ def graph_aligner(n_nodes: int, seq_len: int, max_pred: int, match: int,
                 H, new_row[:, None, :], (jnp.int32(0), k, jnp.int32(0)))
             return H, bp_row
 
-        ks = jnp.arange(1, N + 1, dtype=jnp.int32)
-        # unroll on TPU: the scan body is small relative to the While-loop
+        # the node sweep stops at the batch's largest live graph: rows
+        # past it are pad nodes nothing reads, so the trip count follows
+        # the batch, not the bucket's N (one program per bucket still)
+        n_swept = swept_nodes(live_nodes(codes), N)
+        # unroll on TPU: the step is small relative to the While-loop
         # iteration overhead at N=2048 steps; CPU (tests) keeps compiles fast
-        unroll = 4 if jax.default_backend() == "tpu" else 1
-        carry, bps = jax.lax.scan(
-            step, (H, scores0) if W else H,
-            (codes.T, preds.transpose(1, 0, 2), centers.T, ks),
-            unroll=unroll)
-        # bps: [N, B, L+1] int8
+        unroll = (SWEEP_UNROLL if jax.default_backend() == "tpu"
+                  and N % SWEEP_UNROLL == 0 else 1)
+        xs = (codes.T, preds.transpose(1, 0, 2), centers.T)
+        # backpointer plane [N, B, L+1] int8, written in place at row k-1;
+        # an unswept row reads as a vertical move to the first
+        # predecessor, where a pad row's traceback (preds -1) ends
+        bps = jnp.full((N, B, L + 1), P, dtype=jnp.int8)
+
+        def sweep(i, st):
+            carry, bps = st
+            for u in range(unroll):
+                k = i * unroll + (u + 1)
+                x = tuple(jax.lax.dynamic_index_in_dim(a, k - 1, 0, False)
+                          for a in xs)
+                carry, bp_row = step(carry, x + (k,))
+                bps = jax.lax.dynamic_update_index_in_dim(
+                    bps, bp_row, k - 1, 0)
+            return carry, bps
+
+        carry, bps = jax.lax.fori_loop(
+            0, n_swept // unroll, sweep, ((H, scores0) if W else H, bps))
 
         # best sink at the layer's final column; ties -> smallest rank
         # (host: ascending scan keeping strict improvements)
@@ -371,7 +415,7 @@ def graph_aligner(n_nodes: int, seq_len: int, max_pred: int, match: int,
         def body(st):
             r, j, out = st
             active = (r > 0) | (j > 0)
-            # point gathers straight from the [N, B, L+1] scan output:
+            # point gathers straight from the [N, B, L+1] plane:
             # a batch-major flat copy of it makes the TPU compile time
             # grow with the batch width
             rc = jnp.clip(r - 1, 0, N - 1)
@@ -747,13 +791,19 @@ class DeviceGraphPOA:
                              * (jobs["len"][sel].astype(np.int64) + 1))
                 shard_useful = [int(row_cells[s::n_dev].sum())
                                 for s in range(n_dev)]
+                # the XLA program sweeps to the batch's largest graph
+                # (the program's own formula); the pallas sweep is
+                # accounted at the bucket's rows
+                swept = nb if use_pallas else int(swept_nodes(
+                    live_nodes(jobs["codes"][sel, :nb]), nb))
                 self.sched.stats.record(
                     "session", (nb, lb), jobs=len(part), lanes=B,
                     useful_cells=int(row_cells.sum()),
-                    total_cells=B * nb * (lb + 1),
+                    total_cells=B * swept * (lb + 1),
                     kernel="pallas" if use_pallas else "xla", dtype=dtype,
                     n_devices=n_dev, shard_useful=shard_useful,
-                    full_mesh_cells=B * nb * (lb + 1))
+                    full_mesh_cells=B * swept * (lb + 1),
+                    swept_steps=swept, bucket_steps=nb)
                 batches.append(meta + (len(part), lb, out, rows))
         return batches
 

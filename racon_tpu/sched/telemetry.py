@@ -16,7 +16,9 @@ shows up as a measured occupancy delta, not an anecdote.
 
 Invariant the tests pin: per bucket, useful_cells + padded_cells ==
 lanes * capacity(bucket) — the counters sum to exactly the cells the
-device was asked to process.
+device was asked to process. The session engine's capacity is the rows
+a batch's program sweeps (its largest graph, not the bucket's node
+count) times the bucket's length.
 """
 
 from __future__ import annotations
@@ -86,7 +88,9 @@ class OccupancyStats:
                kernel: str | None = None, dtype: str | None = None,
                n_devices: int | None = None,
                shard_useful=None,
-               full_mesh_cells: int | None = None) -> None:
+               full_mesh_cells: int | None = None,
+               swept_steps: int | None = None,
+               bucket_steps: int | None = None) -> None:
         """Account one dispatched batch. `bucket` is any hashable shape
         descriptor (stringified for the snapshot); `total_cells` is the
         batch's full dispatched capacity (>= useful_cells). `kernel`
@@ -103,7 +107,12 @@ class OccupancyStats:
         synthbench's scale curve gates on), and `full_mesh_cells` what
         the batch WOULD have dispatched under full-mesh `round_batch`
         rounding — the baseline the sub-mesh tail dispatch is measured
-        against (equal to `total_cells` when no sub-mesh was taken)."""
+        against (equal to `total_cells` when no sub-mesh was taken).
+
+        `swept_steps` / `bucket_steps` (the session engine) are the node
+        steps the batch's program swept and the bucket's node count it
+        could have swept, summed per bucket: how far the sweep bound
+        cut the program's steps."""
         key = (engine, str(bucket))
         with self._lock:
             b = self._buckets.get(key)
@@ -128,6 +137,10 @@ class OccupancyStats:
             if full_mesh_cells is not None:
                 b["full_mesh_cells"] = (b.get("full_mesh_cells", 0)
                                         + int(full_mesh_cells))
+            if swept_steps is not None:
+                b["swept_steps"] = b.get("swept_steps", 0) + int(swept_steps)
+                b["bucket_steps"] = (b.get("bucket_steps", 0)
+                                     + int(bucket_steps))
 
     def record_pairs(self, engine: str, pairs: int,
                      host: dict[str, int]) -> None:

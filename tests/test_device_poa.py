@@ -24,7 +24,8 @@ jax = pytest.importorskip("jax")
 from racon_tpu.core.window import Window, WindowType
 from racon_tpu.native import PoaSession, edit_distance, poa_batch
 from racon_tpu.ops.poa import BatchPOA
-from racon_tpu.ops.poa_graph import DeviceGraphPOA, graph_aligner
+from racon_tpu.ops.poa_graph import (RING, DeviceGraphPOA, graph_aligner,
+                                     live_nodes, swept_nodes)
 from racon_tpu.parallel.mesh import BatchRunner
 
 ACGT = b"ACGT"
@@ -166,6 +167,82 @@ def test_ring_carry_boundary_distance():
     assert fn_full is full and fn_ring is not full
 
 
+def _dag_batch(rng, n_list, n_nodes, seq_len, rows):
+    """Densified jobs for chains of the given node counts with extra
+    short back-edges (still topo-ordered), one mutated layer each, half
+    of them banded; rows past len(n_list) are pad rows as the dispatcher
+    fills them (codes 5, preds -1, no sink, length 0)."""
+    ts = [bytes(rng.choice(ACGT) for _ in range(n)) for n in n_list]
+    qs = [(mutate(rng, t, 0.15) or b"A")[:seq_len] for t in ts]
+    args = linear_graph_inputs(ts, qs, n_nodes, seq_len)
+    preds, band = args[1], args[6]
+    for k, n in enumerate(n_list):
+        for r in range(2, n):
+            if rng.random() < 0.2:
+                # rank r also follows rank r - d (DP row r - d + 1)
+                preds[k, r, 1] = r - rng.randrange(2, min(r, 5) + 1) + 1
+        band[k] = 16 * (k % 2)
+    fill = (5, -1, 0, 0, 5, 0, 0)
+    return [np.concatenate([a, np.full((rows - len(n_list),) + a.shape[1:],
+                                       f, dtype=a.dtype)])
+            for a, f in zip(args, fill)]
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+@pytest.mark.parametrize("packed", [False, True])
+@pytest.mark.parametrize("score_dtype", ["int32", "int16"])
+@pytest.mark.parametrize("ring", [0, RING])
+def test_bounded_sweep_identical_to_full_sweep(monkeypatch, ring,
+                                               score_dtype, packed,
+                                               backend):
+    """The node sweep stops at the batch's largest graph, and its ranks
+    are byte-identical to a full sweep of the bucket: the same rows in
+    a batch that also holds a bucket-sized graph (which forces the full
+    sweep), and each real row alone in a batch (swept to its own size).
+    Batches: rows from 2 nodes to exactly the bucket; fewer jobs than
+    rows (all-pad rows); a largest graph that is not a multiple of the
+    unroll; pad rows alone. `tpu` runs the chip's branch of the program,
+    the sweep unrolled SWEEP_UNROLL steps an iteration, and first checks
+    its full sweeps against the single-step program's."""
+    from racon_tpu.ops.encode import pack_2bit
+
+    N, L, B = 192, 64, 8
+    kwargs = dict(ring=ring, score_dtype=score_dtype, packed_seq=packed)
+
+    def run(fn, args):
+        args = list(args)
+        if packed:
+            args[4] = pack_2bit(args[4])
+        return np.asarray(fn(*args))
+
+    rng = random.Random(29)
+    cases = []
+    for n_list, swept in (([2, 3, 17, 50, 101, 150, 191, N], N),
+                          ([40, 90, 127], 128),
+                          ([5, 20, 37, 33, 12, 8, 30, 37], 40),
+                          ([], 0)):
+        args = _dag_batch(rng, n_list, N, L, B)
+        assert swept_nodes(int(live_nodes(args[0])), N) == swept
+        full = [np.concatenate(x)
+                for x in zip(args, _dag_batch(rng, [N], N, L, 1))]
+        cases.append((n_list, args, full))
+    fn = graph_aligner(N, L, 4, 5, -4, -8, **kwargs)
+    refs = [run(fn, full)[:B] for _, _, full in cases]
+    if backend == "tpu":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        fn = graph_aligner.__wrapped__(N, L, 4, 5, -4, -8, **kwargs)
+        for (_, _, full), ref in zip(cases, refs):
+            np.testing.assert_array_equal(run(fn, full)[:B], ref)
+    for (n_list, args, _), ref in zip(cases, refs):
+        np.testing.assert_array_equal(run(fn, args), ref)
+        for k in range(len(n_list)):
+            solo = _dag_batch(rng, [], N, L, B)
+            for a, src in zip(solo, args):
+                a[0] = src[k]
+            np.testing.assert_array_equal(run(fn, solo)[0], ref[k],
+                                          err_msg=f"{n_list} row {k}")
+
+
 def _make_windows(rng, n_windows, length=60, depth=6, rate=0.08,
                   spanning=True):
     windows = []
@@ -256,6 +333,69 @@ def _block_swap_windows(rng):
         w.add_layer(b"C" * 300 + b"A" * 300, None, 0, len(bb) - 1)
         windows.append(w)
     return windows
+
+
+@pytest.mark.parametrize("buckets", [((96, 96), (192, 128)),
+                                     ((130, 128),)])
+def test_session_sweep_accounting(monkeypatch, buckets):
+    """Each batch is accounted at the rows its program sweeps: the
+    host's largest graph (the program's formula, and the session's node
+    counts) rounded up to the unroll, or single steps in a bucket that
+    is not a multiple of it; swept_steps and bucket_steps sum them per
+    bucket, and useful + padded cells are B x swept x (len + 1). Batches
+    of one bucket with different sweeps run one compiled program. The
+    consensus stays the host engine's."""
+    rng = random.Random(31)
+    windows, _ = _make_windows(rng, 10, length=80, depth=6)
+    packed = [_pack(w) for w in windows]
+    nb_max, lb_max = buckets[-1]
+    B = 8
+    eng = DeviceGraphPOA(3, -5, -4, num_threads=2, max_nodes=nb_max,
+                         max_len=lb_max, buckets=buckets, batch_rows=B)
+    seen, programs = [], {}
+    run_bucket, scan_kernel = eng._run_bucket, eng._scan_kernel
+
+    def spy_bucket(nb, lb, codes, *rest):
+        seen.append((nb, lb, codes.copy(), rest[-1].copy()))
+        return run_bucket(nb, lb, codes, *rest)
+
+    def spy_kernel(*a, **kw):
+        fn = scan_kernel(*a, **kw)
+        programs.setdefault(fn, (fn._cache_size(), set()))[1].add(
+            len(seen) - 1)
+        return fn
+
+    monkeypatch.setattr(eng, "_run_bucket", spy_bucket)
+    monkeypatch.setattr(eng, "_scan_kernel", spy_kernel)
+    dev, st = eng.consensus(packed)
+    host = poa_batch(packed, 3, -5, -4, n_threads=2)
+    assert (st == 0).all(), st.tolist()
+    assert [c for c, _ in dev] == [c for c, _ in host]
+
+    live_on_device = jax.jit(live_nodes)
+    expect: dict = {}
+    for nb, lb, codes, nnodes in seen:
+        n_live = int(nnodes.max())
+        assert int(live_nodes(codes)) == n_live
+        assert int(live_on_device(codes)) == n_live
+        unit = 4 if nb % 4 == 0 else 1
+        swept = -(-n_live // unit) * unit
+        e = expect.setdefault(str((nb, lb)), [0, 0, 0])
+        e[0] += swept
+        e[1] += nb
+        e[2] += B * swept * (lb + 1)
+    snap = eng.sched.stats.snapshot()["session"]
+    assert set(snap["buckets"]) == set(expect)
+    for key, (swept, steps, cells) in expect.items():
+        b = snap["buckets"][key]
+        assert (b["swept_steps"], b["bucket_steps"]) == (swept, steps)
+        assert b["useful_cells"] + b["padded_cells"] == cells
+    assert snap["total_cells"] == sum(e[2] for e in expect.values())
+    # batches of differing sweeps through one program compiled it once
+    assert any(len({int(seen[i][3].max()) for i in idx}) > 1
+               for _, idx in programs.values())
+    for fn, (before, _) in programs.items():
+        assert fn._cache_size() <= before + 1
 
 
 def test_device_banded_retry_byte_identical():
